@@ -65,7 +65,6 @@ def main() -> None:
                                + flag).strip()
     import jax                      # noqa: E402 — after the device flag
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     import common                   # noqa: E402 — benchmarks/ is sys.path[0]
@@ -74,6 +73,9 @@ def main() -> None:
                            build_mesh)
     from repro.dist import collectives
     from repro.dist.sharding import ef_residual_sharding, stacked_tree
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     # the bench measures the same declarative config surface the
     # launcher trains: one RunSpec per (mesh, compression) cell
@@ -106,80 +108,79 @@ def main() -> None:
             spec = jax.tree.map(
                 lambda leaf: P(("data",), *([None] * (leaf.ndim - 1))),
                 tree)
-            return shard_map(
+            return jax.shard_map(
                 lambda t: jax.tree.map(
                     lambda x: jax.lax.pmean(x[0], ("data",)), t),
                 mesh=mesh_obj, in_specs=(spec,),
                 out_specs=jax.tree.map(
                     lambda leaf: P(*([None] * (leaf.ndim - 1))), tree),
-                check_rep=False)(tree)
+                check_vma=False)(tree)
         return fp32_pmean
 
     if args.profile:
         jax.profiler.start_trace(args.profile)
 
     rows = []
-    with mesh:
-        placed = jax.device_put(stacked,
-                                ef_residual_sharding(stacked, mesh))
-        # fp32 baseline: the ring all-reduce the wire path replaces
-        st = time_reduce(jax.jit(fp32_pmean_for(mesh)), placed)
-        fp32_ms = st["p50_ms"]
-        fp32_bytes = sum(collectives.fp32_allreduce_bytes(x.size, n)
-                         for x in leaves)
-        rows.append({"mode": "fp32", "bytes_on_wire_per_device": fp32_bytes,
-                     "bytes_per_element": round(fp32_bytes / elements, 3),
-                     "step_ms": round(st["p50_ms"], 2),
-                     "p50_ms": round(st["p50_ms"], 2),
-                     "p90_ms": round(st["p90_ms"], 2),
-                     "reduction_vs_fp32": 1.0})
-        for kind in ("bf16", "int8"):
-            fn = jax.jit(lambda t, k=kind:
-                         collectives.ef_wire_pmean(t, mesh, k))
-            with collectives.record_wire_bytes() as rec:
-                fn.lower(placed)                    # trace -> record bytes
-            st = time_reduce(fn, placed)
-            b = rec.total()
-            rows.append({
-                "mode": f"{kind}-wire",
-                "bytes_on_wire_per_device": b,
-                "bytes_per_element": round(b / elements, 3),
-                "step_ms": round(st["p50_ms"], 2),
-                "p50_ms": round(st["p50_ms"], 2),
-                "p90_ms": round(st["p90_ms"], 2),
-                "step_ratio_vs_fp32": round(st["p50_ms"] / fp32_ms, 3),
-                "reduction_vs_fp32": round(fp32_bytes / b, 2)})
+    placed = jax.device_put(stacked,
+                            ef_residual_sharding(stacked, mesh))
+    # fp32 baseline: the ring all-reduce the wire path replaces
+    st = time_reduce(jax.jit(fp32_pmean_for(mesh)), placed)
+    fp32_ms = st["p50_ms"]
+    fp32_bytes = sum(collectives.fp32_allreduce_bytes(x.size, n)
+                     for x in leaves)
+    rows.append({"mode": "fp32", "bytes_on_wire_per_device": fp32_bytes,
+                 "bytes_per_element": round(fp32_bytes / elements, 3),
+                 "step_ms": round(st["p50_ms"], 2),
+                 "p50_ms": round(st["p50_ms"], 2),
+                 "p90_ms": round(st["p90_ms"], 2),
+                 "reduction_vs_fp32": 1.0})
+    for kind in ("bf16", "int8"):
+        fn = jax.jit(lambda t, k=kind:
+                     collectives.ef_wire_pmean(t, mesh, k))
+        with collectives.record_wire_bytes() as rec:
+            fn.lower(placed)                    # trace -> record bytes
+        st = time_reduce(fn, placed)
+        b = rec.total()
+        rows.append({
+            "mode": f"{kind}-wire",
+            "bytes_on_wire_per_device": b,
+            "bytes_per_element": round(b / elements, 3),
+            "step_ms": round(st["p50_ms"], 2),
+            "p50_ms": round(st["p50_ms"], 2),
+            "p90_ms": round(st["p90_ms"], 2),
+            "step_ratio_vs_fp32": round(st["p50_ms"] / fp32_ms, 3),
+            "reduction_vs_fp32": round(fp32_bytes / b, 2)})
 
-        # ---- mixed-precision section: every packable matmul layer on the
-        # int4 nibble wire (a learned PrecisionPlan's maximal mixed plan),
-        # everything else (biases, norms, activation f) at int8 — vs the
-        # uniform int8 wire above
-        from repro.core.plan import mixed_low_plan
-        plan = mixed_low_plan(params, low_bits=4)
-        widths = plan.wire_bits_tree(placed)
-        uniform_b = rows[-1]["bytes_on_wire_per_device"]   # int8-wire
-        fnm = jax.jit(lambda t: collectives.ef_wire_pmean(
-            t, mesh, "int8", widths=widths))
-        with collectives.record_wire_bytes() as recm:
-            fnm.lower(placed)
-        stm = time_reduce(fnm, placed)
-        bm = recm.total()
-        mixed = {
-            "plan_summary": plan.summary(),
-            "low_bits": 4,
-            "runs": [{
-                "mode": "int8-wire-uniform",
-                "bytes_on_wire_per_device": uniform_b,
-                "bytes_per_element": round(uniform_b / elements, 3)},
-                {"mode": "int8-wire-mixed-w4w8",
-                 "bytes_on_wire_per_device": bm,
-                 "bytes_per_element": round(bm / elements, 3),
-                 "step_ms": round(stm["p50_ms"], 2),
-                 "p50_ms": round(stm["p50_ms"], 2),
-                 "p90_ms": round(stm["p90_ms"], 2),
-                 "step_ratio_vs_fp32": round(stm["p50_ms"] / fp32_ms, 3),
-                 "reduction_vs_uniform": round(uniform_b / bm, 2)}],
-        }
+    # ---- mixed-precision section: every packable matmul layer on the
+    # int4 nibble wire (a learned PrecisionPlan's maximal mixed plan),
+    # everything else (biases, norms, activation f) at int8 — vs the
+    # uniform int8 wire above
+    from repro.core.plan import mixed_low_plan
+    plan = mixed_low_plan(params, low_bits=4)
+    widths = plan.wire_bits_tree(placed)
+    uniform_b = rows[-1]["bytes_on_wire_per_device"]   # int8-wire
+    fnm = jax.jit(lambda t: collectives.ef_wire_pmean(
+        t, mesh, "int8", widths=widths))
+    with collectives.record_wire_bytes() as recm:
+        fnm.lower(placed)
+    stm = time_reduce(fnm, placed)
+    bm = recm.total()
+    mixed = {
+        "plan_summary": plan.summary(),
+        "low_bits": 4,
+        "runs": [{
+            "mode": "int8-wire-uniform",
+            "bytes_on_wire_per_device": uniform_b,
+            "bytes_per_element": round(uniform_b / elements, 3)},
+            {"mode": "int8-wire-mixed-w4w8",
+             "bytes_on_wire_per_device": bm,
+             "bytes_per_element": round(bm / elements, 3),
+             "step_ms": round(stm["p50_ms"], 2),
+             "p50_ms": round(stm["p50_ms"], 2),
+             "p90_ms": round(stm["p90_ms"], 2),
+             "step_ratio_vs_fp32": round(stm["p50_ms"] / fp32_ms, 3),
+             "reduction_vs_uniform": round(uniform_b / bm, 2)}],
+    }
 
     # ---- 2D (data x model) section: 1D vs 2D on DxM meshes of n devices
     mesh2d = []
@@ -198,59 +199,58 @@ def main() -> None:
         tp_repl = sum(collectives.tp_replication_bytes(x.shape, M)
                       for x in leaves)
         dm_rows = []
-        with mesh_dm:
-            placed_dm = jax.device_put(
-                stacked_dm, ef_residual_sharding(stacked_dm, mesh_dm))
-            res_placed = jax.device_put(
-                res2d, ef_residual_sharding(res2d, mesh_dm, layout="2d"))
-            # fp32 baseline on THIS mesh: D-device ring all-reduce plus
-            # the fp32 model-axis replication a TP step pays either way
-            st0 = time_reduce(jax.jit(fp32_pmean_for(mesh_dm)), placed_dm)
-            fp32_dm_ms = st0["p50_ms"]
-            fp32_b_dm = sum(collectives.fp32_allreduce_bytes(x.size, D)
-                            for x in leaves)
-            dm_rows.append({
-                "mode": "fp32",
-                "bytes_on_wire_per_device": fp32_b_dm,
-                "tp_replication_bytes": tp_repl,
-                "total_bytes_per_element": round(
-                    (fp32_b_dm + tp_repl) / elements, 3),
-                "step_ms": round(st0["p50_ms"], 2),
-                "p50_ms": round(st0["p50_ms"], 2),
-                "p90_ms": round(st0["p90_ms"], 2)})
-            fn1 = jax.jit(lambda t: collectives.ef_wire_pmean(
-                t, mesh_dm, "int8"))
-            with collectives.record_wire_bytes() as rec1:
-                fn1.lower(placed_dm)
-            st1 = time_reduce(fn1, placed_dm)
-            total1 = rec1.total() + tp_repl
-            dm_rows.append({
-                "mode": "int8-wire",
-                "bytes_on_wire_per_device": rec1.total(),
-                "tp_replication_bytes": tp_repl,
-                "total_bytes_per_element": round(total1 / elements, 3),
-                "step_ms": round(st1["p50_ms"], 2),
-                "p50_ms": round(st1["p50_ms"], 2),
-                "p90_ms": round(st1["p90_ms"], 2),
-                "step_ratio_vs_fp32": round(
-                    st1["p50_ms"] / fp32_dm_ms, 3)})
-            fn2 = jax.jit(lambda t, r: collectives.ef_wire_pmean_2d(
-                t, r, mesh_dm, "int8"))
-            with collectives.record_wire_bytes() as rec2:
-                fn2.lower(placed_dm, res_placed)
-            st2 = time_reduce(lambda _: fn2(placed_dm, res_placed), None)
-            total2 = rec2.total()
-            dm_rows.append({
-                "mode": "int8-wire-2d",
-                "bytes_on_wire_per_device": rec2.total(),
-                "tp_replication_bytes": 0.0,
-                "total_bytes_per_element": round(total2 / elements, 3),
-                "step_ms": round(st2["p50_ms"], 2),
-                "p50_ms": round(st2["p50_ms"], 2),
-                "p90_ms": round(st2["p90_ms"], 2),
-                "step_ratio_vs_fp32": round(
-                    st2["p50_ms"] / fp32_dm_ms, 3),
-                "reduction_vs_1d": round(total1 / total2, 2)})
+        placed_dm = jax.device_put(
+            stacked_dm, ef_residual_sharding(stacked_dm, mesh_dm))
+        res_placed = jax.device_put(
+            res2d, ef_residual_sharding(res2d, mesh_dm, layout="2d"))
+        # fp32 baseline on THIS mesh: D-device ring all-reduce plus
+        # the fp32 model-axis replication a TP step pays either way
+        st0 = time_reduce(jax.jit(fp32_pmean_for(mesh_dm)), placed_dm)
+        fp32_dm_ms = st0["p50_ms"]
+        fp32_b_dm = sum(collectives.fp32_allreduce_bytes(x.size, D)
+                        for x in leaves)
+        dm_rows.append({
+            "mode": "fp32",
+            "bytes_on_wire_per_device": fp32_b_dm,
+            "tp_replication_bytes": tp_repl,
+            "total_bytes_per_element": round(
+                (fp32_b_dm + tp_repl) / elements, 3),
+            "step_ms": round(st0["p50_ms"], 2),
+            "p50_ms": round(st0["p50_ms"], 2),
+            "p90_ms": round(st0["p90_ms"], 2)})
+        fn1 = jax.jit(lambda t: collectives.ef_wire_pmean(
+            t, mesh_dm, "int8"))
+        with collectives.record_wire_bytes() as rec1:
+            fn1.lower(placed_dm)
+        st1 = time_reduce(fn1, placed_dm)
+        total1 = rec1.total() + tp_repl
+        dm_rows.append({
+            "mode": "int8-wire",
+            "bytes_on_wire_per_device": rec1.total(),
+            "tp_replication_bytes": tp_repl,
+            "total_bytes_per_element": round(total1 / elements, 3),
+            "step_ms": round(st1["p50_ms"], 2),
+            "p50_ms": round(st1["p50_ms"], 2),
+            "p90_ms": round(st1["p90_ms"], 2),
+            "step_ratio_vs_fp32": round(
+                st1["p50_ms"] / fp32_dm_ms, 3)})
+        fn2 = jax.jit(lambda t, r: collectives.ef_wire_pmean_2d(
+            t, r, mesh_dm, "int8"))
+        with collectives.record_wire_bytes() as rec2:
+            fn2.lower(placed_dm, res_placed)
+        st2 = time_reduce(lambda _: fn2(placed_dm, res_placed), None)
+        total2 = rec2.total()
+        dm_rows.append({
+            "mode": "int8-wire-2d",
+            "bytes_on_wire_per_device": rec2.total(),
+            "tp_replication_bytes": 0.0,
+            "total_bytes_per_element": round(total2 / elements, 3),
+            "step_ms": round(st2["p50_ms"], 2),
+            "p50_ms": round(st2["p50_ms"], 2),
+            "p90_ms": round(st2["p90_ms"], 2),
+            "step_ratio_vs_fp32": round(
+                st2["p50_ms"] / fp32_dm_ms, 3),
+            "reduction_vs_1d": round(total1 / total2, 2)})
         mesh2d.append({"mesh": f"{D}x{M}", "spec": spec_2d.to_dict(),
                        "runs": dm_rows})
 

@@ -12,6 +12,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="jet|svhn|muon|fig2|kernels|roofline")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     only = args.only
 

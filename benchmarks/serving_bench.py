@@ -178,7 +178,10 @@ def main() -> None:
 
     from repro.api import PrecisionSpec, RunSpec, ServingSpec, build
     from repro.core.plan import LayerPlan, PrecisionPlan
+    from repro.launch.cache import enable_compile_cache
     from repro.serving.packed import pack_tree, packed_nbytes
+
+    enable_compile_cache()
 
     # the bench measures exactly the declarative config the launcher and
     # the serving example run: one RunSpec per mode, coexisting contexts

@@ -41,21 +41,28 @@ COLLECTIVE_OPS = ("all-reduce", "all-gather", "all-to-all",
 SCALAR_MAX = 256
 
 # result is either `dtype[dims]{layout}` or a tuple `(dtype[..]{..}, ...)`
-# (async pairs, multi-operand all-to-all): skip lazily to the op name
+# (async pairs, multi-operand all-to-all): skip lazily to the op name.
+# TPU layouts nest parentheses (`{1,0:T(8,128)(4,1)}`), so the skip
+# cannot stop at the first `)`.
 _COLLECTIVE_RE = re.compile(
-    r"=\s+\(?(\w+)\[([\d,]*)\][^)]*?\)?\s+("
+    r"=\s+\(?(\w+)\[([\d,]*)\].*?\s("
     + "|".join(COLLECTIVE_OPS) + r")(-start)?\(")
 _BRACE_GROUPS_RE = re.compile(r"replica_groups=\{(\{[\d{},]*\})\}")
 _IOTA_GROUPS_RE = re.compile(
     r"replica_groups=\[([\d,]+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?")
 _SOURCE_TARGET_RE = re.compile(r"source_target_pairs=\{([\d{},]*)\}")
+_FRAME_TABLES_RE = re.compile(
+    r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*",
+    re.M)
 
 
 def strip_metadata(hlo: str) -> str:
     """Strip source-location noise from compiled HLO text, for
-    program-identity comparisons: ``metadata={...}`` blocks and every
-    quoted string (op names embed auto-numbered trace paths that are not
-    the program)."""
+    program-identity comparisons: the stack-frame tables at the top of
+    the module (``FileNames`` ... ``StackFrames``), ``metadata={...}``
+    blocks and every quoted string (op names embed auto-numbered trace
+    paths that are not the program)."""
+    hlo = _FRAME_TABLES_RE.sub("", hlo)
     hlo = re.sub(r"metadata=\{[^}]*\}", "", hlo)
     return re.sub(r'"[^"]*"', '""', hlo)
 

@@ -16,10 +16,11 @@ import math
 from typing import Any, Iterator, List, Tuple
 
 # primitives that exchange bytes between devices when bound inside
-# shard_map / pmap.  psum2 is what shard_map rebinds psum to; axis_index
-# and pvary are excluded: they read/adjust replication, nothing moves.
+# shard_map / pmap.  psum_invariant is what a checked (check_vma)
+# shard_map binds psum to; axis_index and pvary are excluded: they
+# read/adjust replication, nothing moves.
 COLLECTIVE_PRIMITIVES = frozenset({
-    "psum", "psum2", "pmax", "pmin", "ppermute", "pbroadcast",
+    "psum", "psum_invariant", "pmax", "pmin", "ppermute", "pbroadcast",
     "all_gather", "all_to_all", "psum_scatter", "reduce_scatter",
 })
 
@@ -88,9 +89,9 @@ def explicit_collectives(jaxpr) -> List[ExplicitCollective]:
         dtype = getattr(aval, "dtype", None)
         shape = tuple(getattr(aval, "shape", ()) or ())
         out.append(ExplicitCollective(
-            # psum2 is jax-internal for psum-under-shard_map: report the
-            # name the user wrote
-            primitive="psum" if name == "psum2" else name,
+            # psum_invariant is jax-internal for psum-under-shard_map:
+            # report the name the user wrote
+            primitive="psum" if name == "psum_invariant" else name,
             axes=_axis_names(eqn.params),
             dtype="" if dtype is None else str(dtype),
             dims=shape))

@@ -67,12 +67,11 @@ def train_traced(spec: RunSpec):
     ``init_training`` builds, traced on its own representative args."""
     ctx = build(spec)
     setup = ctx.init_training()
-    with ctx.mesh:
-        args = [setup.params, setup.qstate, setup.opt,
-                setup.pipeline(0), jnp.int32(0)]
-        if setup.ef_state is not None:
-            args.append(setup.ef_state)
-        traced = setup.jitted.trace(*args)
+    args = [setup.params, setup.qstate, setup.opt,
+            setup.pipeline(0), jnp.int32(0)]
+    if setup.ef_state is not None:
+        args.append(setup.ef_state)
+    traced = setup.jitted.trace(*args)
     return ctx, setup, traced
 
 

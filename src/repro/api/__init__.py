@@ -13,8 +13,7 @@ module-level mutable state.
     spec = RunSpec.from_file("examples/specs/host_2x4_int8wire2d.json")
     ctx = build(spec)
     setup = ctx.init_training()
-    with ctx.mesh:
-        metrics = setup.step(0)
+    metrics = setup.step(0)
 """
 from ..core.plan import LayerPlan, PrecisionPlan  # noqa: F401
 from .spec import (AudioSpec, CompressionSpec,  # noqa: F401

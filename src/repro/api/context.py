@@ -11,8 +11,7 @@ owns:
 
     ctx = repro.api.build(spec)
     setup = ctx.init_training()        # params/opt/EF state + jitted step
-    with ctx.mesh:
-        ... setup.step(...) ...
+    setup.step(0)                      # shardings name ctx.mesh itself
 
     eng = ctx.make_engine(params, qstate)   # serving, same spec surface
 
@@ -31,6 +30,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from ..configs import get as get_config
 from ..data import make_pipeline
@@ -55,8 +55,12 @@ def build_mesh(mspec: MeshSpec):
     A function (never a module-level constant) so importing this module
     touches no jax device state — production meshes need the forced
     host-device XLA flag set before first jax init (``launch.dryrun``).
+    Every axis is ``Auto``: the sharding code constrains activations
+    and lets GSPMD place the rest (``jax.make_mesh`` defaults to
+    ``Explicit`` axes, which reject such constraints).
     """
-    return jax.make_mesh(mspec.shape, mspec.axis_names)
+    return jax.make_mesh(mspec.shape, mspec.axis_names,
+                         axis_types=(AxisType.Auto,) * len(mspec.shape))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -321,11 +325,23 @@ class RunContext:
         comp = self.grad_compression()
         ef_state = comp.init_state(params, self.n_data, self.n_model)
         step_fn = self.make_train_step(loss_fn, comp)
-        with self.mesh:
-            in_shardings, donate = self.train_shardings(
-                params, qstate, opt, ef_state, comp)
-            jitted = jax.jit(step_fn, in_shardings=in_shardings,
-                             donate_argnums=donate)
+        in_shardings, donate = self.train_shardings(
+            params, qstate, opt, ef_state, comp)
+        # the state leaves each step on the shardings it came in on (the
+        # compiler's own choice may differ, and the next call would then
+        # refuse it); metrics are replicated scalars
+        out_shardings = (in_shardings[:3] + (replicated(self.mesh),)
+                         + in_shardings[5:])
+        jitted = jax.jit(step_fn, in_shardings=in_shardings,
+                         out_shardings=out_shardings,
+                         donate_argnums=donate)
+        # place the state where the step leaves it: a step's outputs carry
+        # their mesh in their type, so unplaced first inputs would trace
+        # and compile the whole step a second time at step 1
+        params, qstate, opt = jax.device_put((params, qstate, opt),
+                                             in_shardings[:3])
+        if ef_state is not None:
+            ef_state = jax.device_put(ef_state, in_shardings[5])
         return TrainSetup(self, params, qstate, opt, ef_state, jitted,
                           self.make_pipeline())
 

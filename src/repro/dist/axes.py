@@ -16,14 +16,16 @@ is *scoped*, not global: a :class:`repro.api.RunContext` activates its
 trace via :func:`axis_scope`, so two contexts with different meshes
 coexist in one process.  Outside any scope the immutable default applies
 (single-device identity), so library code is importable and testable with
-no mesh at all.
+no mesh at all.  The registry carries its mesh, so a constraint names its
+devices itself and no ambient ``with mesh:`` context is needed.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from .scope import Scoped
@@ -35,6 +37,7 @@ class AxisRegistry:
     model_axis: str = "model"
     data_size: int = 1
     model_size: int = 1
+    mesh: Optional[Mesh] = None       # set whenever a size exceeds 1
 
 
 _AXES: Scoped[AxisRegistry] = Scoped("repro.dist.axes", AxisRegistry())
@@ -75,7 +78,7 @@ def registry_for_mesh(mesh) -> AxisRegistry:
     for a in daxes:
         dsize *= sizes[a]
     return AxisRegistry(daxes or ("data",), "model", dsize,
-                        int(sizes.get("model", 1)))
+                        int(sizes.get("model", 1)), mesh)
 
 
 def _spec_for(pattern: str, shape: Tuple[int, ...]) -> P:
@@ -109,4 +112,5 @@ def constrain(x: jax.Array, pattern: str) -> jax.Array:
     reg = _AXES.get()
     if reg.data_size * reg.model_size <= 1:
         return x
-    return jax.lax.with_sharding_constraint(x, _spec_for(pattern, x.shape))
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(reg.mesh, _spec_for(pattern, x.shape)))

@@ -72,7 +72,6 @@ from typing import Any, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.plan import NIBBLE_BITS
@@ -590,9 +589,9 @@ def _wire_pmean_impl(e_stacked: Any, mesh, kind: str,
         lambda leaf: P(axes, *([None] * (leaf.ndim - 1))), e_stacked)
     plain_spec = jax.tree.map(
         lambda leaf: P(*([None] * (leaf.ndim - 1))), e_stacked)
-    return shard_map(body, mesh=mesh, in_specs=(stack_spec,),
+    return jax.shard_map(body, mesh=mesh, in_specs=(stack_spec,),
                      out_specs=(plain_spec, stack_spec),
-                     check_rep=False)(e_stacked)
+                     check_vma=False)(e_stacked)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
@@ -1173,8 +1172,8 @@ def _wire2d_impl(grads_stacked: Any, residual: Any, mesh, kind: str,
         return delivered, new_res
 
     gin, rspec, dout = _wire2d_specs(grads_stacked, mesh)
-    return shard_map(body, mesh=mesh, in_specs=(gin, rspec),
-                     out_specs=(dout, rspec), check_rep=False)(
+    return jax.shard_map(body, mesh=mesh, in_specs=(gin, rspec),
+                     out_specs=(dout, rspec), check_vma=False)(
                          grads_stacked, residual)
 
 

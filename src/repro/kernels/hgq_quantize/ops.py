@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,11 +38,12 @@ def _to_2d(x: jax.Array):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def hgq_quantize(x: jax.Array, f: jax.Array, epsilon: float = 0.5,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """Differentiable HGQ quantizer (Alg. 1) backed by the Pallas kernel.
 
     f: scalar (per_tensor), [x.shape[-1]] (per_channel) or x.shape
-    (per_parameter).
+    (per_parameter).  ``interpret=None`` compiles the kernel on TPU and
+    interprets it elsewhere; pass a bool to override.
     """
     return _forward(x, f, epsilon, interpret)
 

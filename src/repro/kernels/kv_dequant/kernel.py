@@ -13,7 +13,9 @@ of fp — the read kernel touches each cache byte exactly once:
     mantissas with the k exponents folded into the score columns, online
     mask/softmax, probs requantization, and the v exponents folded into
     the prob rows — the cache never exists dequantized in HBM.  Nibble-
-    packed (``kv_bits <= 4``) caches unpack in VMEM.
+    packed (``kv_bits <= 4``) caches unpack in VMEM into lo/hi planes
+    (even head columns, then odd), and ops.py hands the queries in and
+    takes the outputs back in that planar column order.
 
 The exponent application rides the last (slot) axis of the score matrix,
 so both dequants are row-vector broadcasts — no transposed per-column
@@ -26,11 +28,14 @@ numerically tight); ``ops.py`` picks the backend and handles padding.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ..backend import resolve_interpret
 from ..hgq_quantize.kernel import DEFAULT_BLOCK_ROWS, LANE, _exact_exp2
 from ..wire_pack.kernel import _floor_log2_pos
 
@@ -46,13 +51,18 @@ def _grid_exponent_math(amax, qmax):
                      fcap - 1.0, fcap)
 
 
-def _unpack_math(packed, hd):
-    """[W, hd // 2] nibble bytes -> [W, hd] sign-extended int8 mantissas
-    (arithmetic shifts, the exact ``qmatmul.unpack_nibbles`` math)."""
-    lo = jax.lax.shift_right_arithmetic(
-        jax.lax.shift_left(packed, jnp.int8(4)), jnp.int8(4))
-    hi = jax.lax.shift_right_arithmetic(packed, jnp.int8(4))
-    return jnp.stack([lo, hi], axis=-1).reshape(packed.shape[0], hd)
+def _unpack_planes(packed):
+    """[W, hdm] nibble bytes -> [W, 2 * hdm] sign-extended mantissas in
+    planar order: the low nibbles (even head columns) fill the first hdm
+    lanes, the high nibbles (odd columns) the rest.  Up to that column
+    order this is ``qmatmul.unpack_nibbles``; Mosaic cannot interleave
+    lanes, and hdm is lane-aligned, so the planes concatenate in place.
+    The shifts run in int32 (Mosaic has no int8 shifts); arithmetic
+    shifts of the sign-extended byte give the int8 results exactly."""
+    p = packed.astype(jnp.int32)
+    lo = jax.lax.shift_right_arithmetic(jax.lax.shift_left(p, 28), 28)
+    hi = jax.lax.shift_right_arithmetic(p, 4)
+    return jnp.concatenate([lo, hi], axis=-1)
 
 
 def _kv_quantize_kernel(x_ref, q_ref, f_ref, *, qmax):
@@ -70,13 +80,13 @@ def _kv_dequant_kernel(q_ref, f_ref, o_ref):
 
 
 def _kv_attention_kernel(q_ref, km_ref, kf_ref, vm_ref, vf_ref, mask_ref,
-                         pf_ref, o_ref, *, scale, packed, hd, use_pf):
+                         pf_ref, o_ref, *, scale, packed, use_pf):
     qc = q_ref[0, 0]                                # [SG, hd] fp32
     km = km_ref[0, 0]                               # [W, hdm] int8
     vm = vm_ref[0, 0]
     if packed:
-        km = _unpack_math(km, hd)
-        vm = _unpack_math(vm, hd)
+        km = _unpack_planes(km)
+        vm = _unpack_planes(vm)
     kf = kf_ref[0, 0, 0].astype(jnp.float32)        # [W]
     vf = vf_ref[0, 0, 0].astype(jnp.float32)
     maskb = mask_ref[0] != 0                        # [SG, W]
@@ -91,7 +101,9 @@ def _kv_attention_kernel(q_ref, km_ref, kf_ref, vm_ref, vf_ref, mask_ref,
     pt = jnp.where(maskb, pt, 0.0)
     if use_pf:
         # quantize_inference on the probs grid: floor(p * 2^f + 0.5) * 2^-f
-        pf = _exact_exp2(jnp.floor(pf_ref[0, 0] + 0.5))
+        # pf is an SMEM scalar: broadcast before the exponent bitcast
+        pf = _exact_exp2(jnp.floor(
+            jnp.full((1, pt.shape[-1]), pf_ref[0, 0]) + 0.5))
         pt = jnp.floor(pt * pf + 0.5) / pf
     l = jnp.sum(pt, axis=-1, keepdims=True)
     pv = (pt / jnp.maximum(l, 1e-20)) * _exact_exp2(-vf)[None, :]
@@ -103,7 +115,7 @@ def _kv_attention_kernel(q_ref, km_ref, kf_ref, vm_ref, vf_ref, mask_ref,
                                              "interpret"))
 def kv_quantize_rows(rows: jax.Array, *, bits: int = 8,
                      block_rows: int = DEFAULT_BLOCK_ROWS,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """[R, hd] fp32 rows -> (int8 mantissas [R, hd], int8 grid exponents
     [R]); hd must be lane-aligned (ops.py pads with zeros, which never
     move a row's amax)."""
@@ -123,7 +135,7 @@ def kv_quantize_rows(rows: jax.Array, *, bits: int = 8,
         out_specs=[tile, col],
         out_shape=[jax.ShapeDtypeStruct((R, P), jnp.int8),
                    jax.ShapeDtypeStruct((R, 1), jnp.int8)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows.astype(jnp.float32))
     return q, f[:, 0]
 
@@ -131,7 +143,7 @@ def kv_quantize_rows(rows: jax.Array, *, bits: int = 8,
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def kv_dequant_rows(q: jax.Array, f: jax.Array, *,
                     block_rows: int = DEFAULT_BLOCK_ROWS,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """[R, hd] int8 mantissas + [R] int8 exponents -> fp32 ``q * 2^-f``."""
     R, P = q.shape
     assert P % LANE == 0, f"cols {P} must be lane-aligned"
@@ -145,7 +157,7 @@ def kv_dequant_rows(q: jax.Array, f: jax.Array, *,
         in_specs=[tile, col],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((R, P), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, f.reshape(R, 1))
 
 
@@ -154,7 +166,7 @@ def kv_dequant_rows(q: jax.Array, f: jax.Array, *,
 def kv_attention_rows(qg: jax.Array, km: jax.Array, kf: jax.Array,
                       vm: jax.Array, vf: jax.Array, mask: jax.Array,
                       pf: jax.Array, *, scale: float, packed: bool,
-                      use_pf: bool, interpret: bool = True):
+                      use_pf: bool, interpret: Optional[bool] = None):
     """Fused dequant-attention decode read, one (batch row, kv head) per
     grid cell.
 
@@ -165,19 +177,20 @@ def kv_attention_rows(qg: jax.Array, km: jax.Array, kf: jax.Array,
     (0 = slot invisible to that query row); ``pf`` [1, 1] fp32 probs
     grid exponent (read iff ``use_pf``).  W and hd lane-aligned
     (ops.py pads; padded slots carry mask 0).  Returns [B, KV, SG, hd]
-    fp32 attention outputs.
+    fp32 attention outputs.  Packed, ``qg`` and the result carry their
+    head columns in the planar order of :func:`_unpack_planes`.
     """
     B, KV, SG, HD = qg.shape
     W = km.shape[2]
     assert HD % LANE == 0 and W % LANE == 0, (HD, W)
     hdm = km.shape[3]
     kern = functools.partial(_kv_attention_kernel, scale=scale,
-                             packed=packed, hd=HD, use_pf=use_pf)
+                             packed=packed, use_pf=use_pf)
     q_spec = pl.BlockSpec((1, 1, SG, HD), lambda b, k: (b, k, 0, 0))
     m_spec = pl.BlockSpec((1, 1, W, hdm), lambda b, k: (b, k, 0, 0))
     f_spec = pl.BlockSpec((1, 1, 1, W), lambda b, k: (b, k, 0, 0))
     mask_spec = pl.BlockSpec((1, SG, W), lambda b, k: (b, 0, 0))
-    pf_spec = pl.BlockSpec((1, 1), lambda b, k: (0, 0))
+    pf_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kern,
         grid=(B, KV),
@@ -185,6 +198,6 @@ def kv_attention_rows(qg: jax.Array, km: jax.Array, kf: jax.Array,
                   pf_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, SG, HD), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qg.astype(jnp.float32), km, kf, vm, vf, mask,
       pf.reshape(1, 1).astype(jnp.float32))

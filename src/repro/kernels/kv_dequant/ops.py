@@ -1,11 +1,10 @@
 """Backend dispatch + shape handling for the quantized-KV-cache kernels.
 
-Same contract as ``wire_pack.ops``: on TPU the compiled Pallas kernels
+Dispatch follows ``kernels.backend``: on TPU the compiled Pallas kernels
 are the fast path; elsewhere the jnp reference is — XLA fuses the
-dequant into the attention einsums on CPU/GPU, where interpret-mode
-Pallas would only add overhead.  ``use_kernel``/``interpret`` overrides
-exist so tests can force the kernel route (interpreted) and pin it
-against the reference on any backend.
+dequant into the attention einsums on CPU/GPU.  ``use_kernel``/
+``interpret`` overrides exist so tests can force the kernel route
+(interpreted) and pin it against the reference on any backend.
 
 Entry points accept the cache-native layouts of ``serving/kvcache.py``
 (``[B, W, KV, hd]`` mantissas, ``[B, W, KV]`` exponents); lane alignment
@@ -20,25 +19,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..backend import resolve, use_fused_kernel
 from . import kernel, ref
 from .kernel import LANE
 
 __all__ = ["kv_attention_decode", "kv_dequant", "kv_pack", "kv_quantize",
            "kv_unpack", "use_fused_kernel"]
-
-
-def use_fused_kernel() -> bool:
-    """True when the compiled Pallas fast path should run (TPU); the
-    reference jnp path IS the fast path elsewhere."""
-    return jax.default_backend() == "tpu"
-
-
-def _resolve(use_kernel: Optional[bool], interpret: Optional[bool]):
-    if use_kernel is None:
-        use_kernel = use_fused_kernel()
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return use_kernel, interpret
 
 
 def _pad_last(x: jax.Array, mult: int, value=0) -> jax.Array:
@@ -56,7 +42,7 @@ def kv_quantize(x: jax.Array, bits: int = 8, *,
     """``[..., hd]`` fp k/v rows -> (int8 mantissas ``[..., hd]``, int8
     grid exponents ``[...]``): amax over the head dim, capped 2^-f grid,
     saturating round — the cache-store quantizer."""
-    use_kernel, interpret = _resolve(use_kernel, interpret)
+    use_kernel, interpret = resolve(use_kernel, interpret)
     if not use_kernel:
         return ref.kv_quantize_ref(x, bits)
     lead, hd = x.shape[:-1], x.shape[-1]
@@ -70,7 +56,7 @@ def kv_dequant(q: jax.Array, f: jax.Array, *,
                interpret: Optional[bool] = None) -> jax.Array:
     """(int8 mantissas ``[..., hd]``, int8 exponents ``[...]``) -> fp32
     ``q * 2^-f``."""
-    use_kernel, interpret = _resolve(use_kernel, interpret)
+    use_kernel, interpret = resolve(use_kernel, interpret)
     if not use_kernel:
         return ref.kv_dequant_ref(q, f)
     lead, hd = q.shape[:-1], q.shape[-1]
@@ -107,7 +93,7 @@ def kv_attention_decode(qh: jax.Array, km: jax.Array, kf: jax.Array,
     Returns [B, S, H, hd] in ``qh.dtype`` — same contract as
     ``nn.attention._decode_attention`` on a dequantized cache.
     """
-    use_kernel, interpret = _resolve(use_kernel, interpret)
+    use_kernel, interpret = resolve(use_kernel, interpret)
     B, S, H, hd = qh.shape
     KV = n_kv
     G = H // KV
@@ -129,12 +115,14 @@ def kv_attention_decode(qh: jax.Array, km: jax.Array, kf: jax.Array,
     if window is not None:
         mask &= (qpos[:, :, None] - tpos[:, None, :]) < window
     mask = jnp.repeat(mask.astype(jnp.int8), G, axis=1)  # [B, SG, W]
+    km2, vm2 = _pad_last(km2, LANE), _pad_last(vm2, LANE)
     if packed:
-        hdm = (-(-km.shape[-1] // LANE)) * LANE
-        km2, vm2 = _pad_last(km2, LANE), _pad_last(vm2, LANE)
+        # the kernel unpacks to planar columns (even, then odd): hand it
+        # the queries in that order
+        hdm = km2.shape[-1]
         qg2 = _pad_last(qg2.astype(jnp.float32), 2 * hdm)
+        qg2 = jnp.concatenate([qg2[..., 0::2], qg2[..., 1::2]], axis=-1)
     else:
-        km2, vm2 = _pad_last(km2, LANE), _pad_last(vm2, LANE)
         qg2 = _pad_last(qg2.astype(jnp.float32), LANE)
     # ring-slot axis: padded slots carry mask 0 and contribute nothing
     Wp = (-(-W // LANE)) * LANE
@@ -148,5 +136,9 @@ def kv_attention_decode(qh: jax.Array, km: jax.Array, kf: jax.Array,
     out = kernel.kv_attention_rows(
         qg2, km2, kf2, vm2, vf2, mask, pf, scale=float(hd) ** -0.5,
         packed=packed, use_pf=probs_f is not None, interpret=interpret)
+    if packed:
+        # planar -> interleaved head columns
+        out = out.reshape(B, KV, S * G, 2, hdm).swapaxes(-1, -2).reshape(
+            B, KV, S * G, 2 * hdm)
     out = out[..., :hd].reshape(B, KV, S, G, hd)
     return out.transpose(0, 2, 1, 3, 4).reshape(B, S, H, hd).astype(qh.dtype)

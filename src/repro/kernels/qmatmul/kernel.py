@@ -12,11 +12,14 @@ scale is constant along K).  MXU-aligned defaults (128, 128, 512).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..backend import resolve_interpret
 
 
 DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 128, 128, 512
@@ -39,10 +42,11 @@ def _qmatmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, k_steps: int):
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def qmatmul(x: jax.Array, w_int: jax.Array, scale: jax.Array, *,
             bm: int = DEFAULT_BM, bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
-            interpret: bool = True) -> jax.Array:
+            interpret: Optional[bool] = None) -> jax.Array:
     """x [M, K] fp; w_int [K, N] int8; scale [N].  Returns [M, N] in x.dtype.
 
-    M, K, N are padded to tile boundaries by ops.py.
+    M, K, N are padded to tile boundaries by ops.py.  ``interpret=None``
+    compiles on TPU and interprets elsewhere.
     """
     M, K = x.shape
     K2, N = w_int.shape
@@ -62,5 +66,5 @@ def qmatmul(x: jax.Array, w_int: jax.Array, scale: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w_int, scale.reshape(1, N))

@@ -19,12 +19,6 @@ from .kernel import qmatmul
 from .ref import pack_ref
 
 
-def default_interpret() -> bool:
-    """Pallas execution mode for the current backend: compiled on TPU,
-    interpreted elsewhere (the kernel uses TPU VMEM scratch semantics)."""
-    return jax.default_backend() != "tpu"
-
-
 def mantissa_max(bits: int = 8) -> int:
     """Largest symmetric mantissa a ``bits``-wide signed grid carries
     (127 for int8, 7 for int4 — -2^(b-1) is excluded so chunk sums and
@@ -135,9 +129,7 @@ def qmatmul_any(x: jax.Array, w_int: jax.Array, scale: jax.Array, *,
                 bn: int = 128, bk: int = 512) -> jax.Array:
     """x [..., K] @ packed w [K, N]: flattens leading dims and pads to the
     (8, 128) tile grid.  ``interpret=None`` selects per backend
-    (:func:`default_interpret`); pass a bool to override."""
-    if interpret is None:
-        interpret = default_interpret()
+    (``kernels.backend.default_interpret``); pass a bool to override."""
     K, N = w_int.shape
     lead = x.shape[:-1]
     M = math.prod(lead) if lead else 1

@@ -4,14 +4,16 @@ Phase 1 of the int8-on-the-wire exchange is three elementwise sweeps in
 the reference path — grid-exponent from the pmax'd amax, saturating
 quantize, nibble pack — plus a fourth to materialize the error-feedback
 residual.  Each kernel here fuses its stage into one VMEM pass over
-(block_rows, lanes) tiles:
+(block_rows, block_cols) tiles — columns are tiled too, because one
+bucket row of a full-size model is far wider than VMEM:
 
   * ``wire_quantize_rows``  — amax -> 2^-f grid -> round/clip -> int8
     mantissas AND the fp32 residual, per stacked-layer row, one pass
   * ``wire_quantize_sflat`` — same with a per-position scale (the 2D
     sliced path, where one device's slice crosses layer-row boundaries)
   * ``wire_pack_rows``      — two int4 mantissas per byte (wire format
-    of sub-5-bit plan widths), lifted from ``qmatmul.pack_nibbles``
+    of sub-5-bit plan widths), ``qmatmul.pack_nibbles`` on lo/hi planes
+    that ops.py splits off (Mosaic cannot interleave lanes)
   * ``wire_dequant_rows``   — phase-2 decode ``q * 2^shift * s / n``
 
 The grid math reuses ``hgq_quantize``'s exact exponent-field exp2
@@ -24,12 +26,18 @@ asserted bit-identical to (tests/test_wire_pack.py, interpret mode);
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..backend import resolve_interpret
 from ..hgq_quantize.kernel import DEFAULT_BLOCK_ROWS, LANE, _exact_exp2
+
+# 256 x 1024 fp32 tiles: 1 MiB each, so the widest kernel (two fp32
+# inputs, fp32 + int8 outputs, double-buffered) stays near 7 MiB of VMEM
+DEFAULT_BLOCK_COLS = 1024
 
 
 def _floor_log2_pos(x):
@@ -70,35 +78,45 @@ def _quantize_sflat_kernel(x_ref, s_ref, q_ref, r_ref, *, qmax):
     r_ref[...] = x - q * s
 
 
-def _pack_kernel(q_ref, o_ref):
-    q = q_ref[...]
-    br, c = q.shape
-    pairs = q.reshape(br, c // 2, 2)
-    o_ref[...] = jnp.bitwise_or(
-        jnp.bitwise_and(pairs[..., 0], jnp.int8(0x0F)),
-        jnp.left_shift(pairs[..., 1], 4)).astype(jnp.int8)
+def _pack_kernel(lo_ref, hi_ref, o_ref):
+    # int32 math (Mosaic has no int8 shifts); the packed byte lies in
+    # [-128, 127], so the narrowing cast is exact
+    lo = lo_ref[...].astype(jnp.int32)
+    hi = hi_ref[...].astype(jnp.int32)
+    o_ref[...] = jnp.bitwise_or(jnp.bitwise_and(lo, 0x0F),
+                                jnp.left_shift(hi, 4)).astype(jnp.int8)
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref, *, mul, n):
     o_ref[...] = q_ref[...].astype(jnp.float32) * mul * s_ref[...] / n
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "block_rows",
-                                             "interpret"))
+def _tiles(R: int, C: int, block_rows: int, block_cols: int):
+    """(grid, [br, bc] tile spec, [br, 1] row-column spec) over an
+    [R, C] array; edge blocks may be partial (elementwise kernels only
+    write in bounds)."""
+    br, bc = min(block_rows, R), min(block_cols, C)
+    tile = pl.BlockSpec((br, bc), lambda i, j: (i, j))
+    col = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
+    return (pl.cdiv(R, br), pl.cdiv(C, bc)), tile, col
+
+
+_STATIC = ("block_rows", "block_cols", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=("bits",) + _STATIC)
 def wire_quantize_rows(rows: jax.Array, amax: jax.Array, *, bits: int = 8,
                        block_rows: int = DEFAULT_BLOCK_ROWS,
-                       interpret: bool = True):
+                       block_cols: int = DEFAULT_BLOCK_COLS,
+                       interpret: Optional[bool] = None):
     """[L, P] fp32 rows + [L] amax -> (int8 [L, P], scale [L],
     residual fp32 [L, P]); P must be lane-aligned (ops.py pads)."""
     from ..qmatmul.ops import mantissa_max
     L, P = rows.shape
     assert P % LANE == 0, f"cols {P} must be lane-aligned"
-    br = min(block_rows, L)
-    grid = (pl.cdiv(L, br),)
+    grid, tile, col = _tiles(L, P, block_rows, block_cols)
     kern = functools.partial(_quantize_rows_kernel,
                              qmax=float(mantissa_max(bits)))
-    tile = pl.BlockSpec((br, P), lambda i: (i, 0))
-    col = pl.BlockSpec((br, 1), lambda i: (i, 0))
     q, s, r = pl.pallas_call(
         kern,
         grid=grid,
@@ -107,25 +125,23 @@ def wire_quantize_rows(rows: jax.Array, amax: jax.Array, *, bits: int = 8,
         out_shape=[jax.ShapeDtypeStruct((L, P), jnp.int8),
                    jax.ShapeDtypeStruct((L, 1), jnp.float32),
                    jax.ShapeDtypeStruct((L, P), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows.astype(jnp.float32), amax.reshape(L, 1).astype(jnp.float32))
     return q, s[:, 0], r
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "block_rows",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("bits",) + _STATIC)
 def wire_quantize_sflat(x: jax.Array, s: jax.Array, *, bits: int = 8,
                         block_rows: int = DEFAULT_BLOCK_ROWS,
-                        interpret: bool = True):
+                        block_cols: int = DEFAULT_BLOCK_COLS,
+                        interpret: Optional[bool] = None):
     """[R, C] fp32 + per-position [R, C] scale -> (int8, residual)."""
     from ..qmatmul.ops import mantissa_max
     R, C = x.shape
     assert C % LANE == 0, f"cols {C} must be lane-aligned"
-    br = min(block_rows, R)
-    grid = (pl.cdiv(R, br),)
+    grid, tile, _ = _tiles(R, C, block_rows, block_cols)
     kern = functools.partial(_quantize_sflat_kernel,
                              qmax=float(mantissa_max(bits)))
-    tile = pl.BlockSpec((br, C), lambda i: (i, 0))
     return pl.pallas_call(
         kern,
         grid=grid,
@@ -133,47 +149,47 @@ def wire_quantize_sflat(x: jax.Array, s: jax.Array, *, bits: int = 8,
         out_specs=[tile, tile],
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.int8),
                    jax.ShapeDtypeStruct((R, C), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x.astype(jnp.float32), s.astype(jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def wire_pack_rows(q: jax.Array, *, block_rows: int = DEFAULT_BLOCK_ROWS,
-                   interpret: bool = True) -> jax.Array:
-    """[R, C] int4-range mantissas -> [R, C // 2] packed bytes; C must be
-    2*lane-aligned so the packed tile stays lane-aligned."""
-    R, C = q.shape
-    assert C % (2 * LANE) == 0, f"cols {C} must be 2*lane-aligned"
-    br = min(block_rows, R)
-    grid = (pl.cdiv(R, br),)
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def wire_pack_rows(lo: jax.Array, hi: jax.Array, *,
+                   block_rows: int = DEFAULT_BLOCK_ROWS,
+                   block_cols: int = DEFAULT_BLOCK_COLS,
+                   interpret: Optional[bool] = None) -> jax.Array:
+    """[R, C] int4-range mantissas of the even (``lo``) and odd (``hi``)
+    columns -> [R, C] packed bytes ``lo & 0xF | hi << 4``; C must be
+    lane-aligned."""
+    R, C = lo.shape
+    assert C % LANE == 0, f"cols {C} must be lane-aligned"
+    grid, tile, _ = _tiles(R, C, block_rows, block_cols)
     return pl.pallas_call(
         _pack_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((br, C), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((br, C // 2), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, C // 2), jnp.int8),
-        interpret=interpret,
-    )(q.astype(jnp.int8))
+        in_specs=[tile, tile],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((R, C), jnp.int8),
+        interpret=resolve_interpret(interpret),
+    )(lo.astype(jnp.int8), hi.astype(jnp.int8))
 
 
-@functools.partial(jax.jit, static_argnames=("shift", "n", "block_rows",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("shift", "n") + _STATIC)
 def wire_dequant_rows(q: jax.Array, s: jax.Array, *, shift: int, n: int,
                       block_rows: int = DEFAULT_BLOCK_ROWS,
-                      interpret: bool = True) -> jax.Array:
+                      block_cols: int = DEFAULT_BLOCK_COLS,
+                      interpret: Optional[bool] = None) -> jax.Array:
     """[R, C] mantissa sums + [R, C] scale -> fp32 ``q * 2^shift * s / n``
     (the phase-2 delivered-mean decode) in one pass."""
     R, C = q.shape
     assert C % LANE == 0, f"cols {C} must be lane-aligned"
-    br = min(block_rows, R)
-    grid = (pl.cdiv(R, br),)
+    grid, tile, _ = _tiles(R, C, block_rows, block_cols)
     kern = functools.partial(_dequant_kernel, mul=float(2 ** shift), n=n)
-    tile = pl.BlockSpec((br, C), lambda i: (i, 0))
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[tile, tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((R, C), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, s.astype(jnp.float32))

@@ -1,11 +1,10 @@
 """Backend dispatch + shape handling for the fused wire kernels.
 
-Same contract as ``qmatmul.ops``: on TPU the compiled Pallas kernel is
-the fast path; elsewhere the jnp reference is — XLA already fuses the
-elementwise chain on CPU/GPU, where interpret-mode Pallas would only
-add overhead.  ``use_kernel``/``interpret`` overrides exist so tests
-can force the kernel route (interpreted) and pin it bit-identical to
-the reference on any backend.
+Dispatch follows ``kernels.backend``: on TPU the compiled Pallas kernel
+is the fast path; elsewhere the jnp reference is — XLA already fuses
+the elementwise chain on CPU/GPU.  ``use_kernel``/``interpret``
+overrides exist so tests can force the kernel route (interpreted) and
+pin it bit-identical to the reference on any backend.
 
 All entry points accept arbitrary shapes; lane alignment (and even-
 column alignment for nibble packing) is handled here by zero/one
@@ -19,26 +18,13 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..backend import resolve, use_fused_kernel
 from . import kernel, ref
 from .kernel import LANE
 from .ref import grid_scale
 
 __all__ = ["dequant_sum", "grid_scale", "pack_chunks", "quantize_chunks",
            "quantize_leaf", "use_fused_kernel"]
-
-
-def use_fused_kernel() -> bool:
-    """True when the compiled Pallas fast path should run (TPU); the
-    reference jnp path IS the fast path elsewhere."""
-    return jax.default_backend() == "tpu"
-
-
-def _resolve(use_kernel: Optional[bool], interpret: Optional[bool]):
-    if use_kernel is None:
-        use_kernel = use_fused_kernel()
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return use_kernel, interpret
 
 
 def _pad_cols(x: jax.Array, mult: int, value: float) -> jax.Array:
@@ -57,7 +43,7 @@ def quantize_leaf(rows: jax.Array, amax: jax.Array, bits: int = 8, *,
     per-row pmax'd amax [L] -> (int8 mantissas, 2^-f scale [L], fp32
     error-feedback residual) — grid exponent, saturating quantize and
     residual in a single pass."""
-    use_kernel, interpret = _resolve(use_kernel, interpret)
+    use_kernel, interpret = resolve(use_kernel, interpret)
     if not use_kernel:
         return ref.quantize_leaf_ref(rows, amax, bits)
     L, P = rows.shape
@@ -73,7 +59,7 @@ def quantize_chunks(e: jax.Array, s: jax.Array, bits: int = 8, *,
                     ) -> Tuple[jax.Array, jax.Array]:
     """Per-position-scale phase-1 (2D sliced path): [R, C] fp32 + [R, C]
     scale -> (int8 mantissas, fp32 residual)."""
-    use_kernel, interpret = _resolve(use_kernel, interpret)
+    use_kernel, interpret = resolve(use_kernel, interpret)
     if not use_kernel:
         return ref.quantize_chunks_ref(e, s, bits)
     R, C = e.shape
@@ -89,12 +75,13 @@ def pack_chunks(q: jax.Array, *, use_kernel: Optional[bool] = None,
     """Nibble-pack int4-range mantissas along the last axis, two per
     byte (odd lengths pad one zero nibble) — the sub-5-bit wire format,
     byte-identical to ``qmatmul.pack_nibbles``."""
-    use_kernel, interpret = _resolve(use_kernel, interpret)
+    use_kernel, interpret = resolve(use_kernel, interpret)
     if not use_kernel:
         return ref.pack_chunks_ref(q)
     lead, C = q.shape[:-1], q.shape[-1]
     q2 = _pad_cols(jnp.asarray(q, jnp.int8).reshape((-1, C)), 2 * LANE, 0)
-    packed = kernel.wire_pack_rows(q2, interpret=interpret)
+    packed = kernel.wire_pack_rows(q2[:, 0::2], q2[:, 1::2],
+                                   interpret=interpret)
     return packed[:, :(C + 1) // 2].reshape(lead + ((C + 1) // 2,))
 
 
@@ -105,7 +92,7 @@ def dequant_sum(q: jax.Array, s: jax.Array, shift: int, n: int, *,
     delivered mean contribution ``q * 2^shift * s / n``.  ``s``
     broadcasts against ``q`` (the 2D path decodes [M, C] blocks against
     a [C] slice scale)."""
-    use_kernel, interpret = _resolve(use_kernel, interpret)
+    use_kernel, interpret = resolve(use_kernel, interpret)
     if not use_kernel:
         return ref.dequant_sum_ref(q, s, shift, n)
     sb = jnp.broadcast_to(jnp.asarray(s, jnp.float32), q.shape)

@@ -160,24 +160,22 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool = False,
         step_fn = ctx.wrap(make_train_step(
             fwd, lambda out, b: lm_loss(out, b["tokens"]),
             TrainConfig(steps=1000)))
-        with mesh:
-            jitted = jax.jit(step_fn,
-                             in_shardings=(params_sh, qstate_sh, opt_sh,
-                                           batch_sh, replicated(mesh)))
-            lowered = jitted.lower(params_abs, qstate_abs, opt_abs,
-                                   batch_abs,
-                                   jax.ShapeDtypeStruct((), jnp.int32))
-            compiled = lowered.compile()
+        jitted = jax.jit(step_fn,
+                         in_shardings=(params_sh, qstate_sh, opt_sh,
+                                       batch_sh, replicated(mesh)))
+        lowered = jitted.lower(params_abs, qstate_abs, opt_abs,
+                               batch_abs,
+                               jax.ShapeDtypeStruct((), jnp.int32))
+        compiled = lowered.compile()
     elif shape.kind == "prefill":
         @ctx.wrap
         def prefill(p, q, b):
             logits, _, _ = M.forward(p, q, b, cfg, mode=hgq.EVAL)
             return logits
-        with mesh:
-            jitted = jax.jit(prefill, in_shardings=(params_sh, qstate_sh,
-                                                    batch_sh))
-            lowered = jitted.lower(params_abs, qstate_abs, batch_abs)
-            compiled = lowered.compile()
+        jitted = jax.jit(prefill, in_shardings=(params_sh, qstate_sh,
+                                                batch_sh))
+        lowered = jitted.lower(params_abs, qstate_abs, batch_abs)
+        compiled = lowered.compile()
     else:  # decode
         max_len = shape.seq_len
         if variant == "opt" and cfg.family not in ("ssm",):
@@ -192,18 +190,17 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool = False,
         def serve_step(p, q, c, tokens, pos):
             return M.decode_step(p, q, c, tokens, pos, cfg)
 
-        with mesh:
-            # per-slot position vector [B]: the continuous-batching ragged
-            # decode step (serving/engine.py) — every slot at its own offset
-            jitted = jax.jit(serve_step,
-                             in_shardings=(params_sh, qstate_sh, caches_sh,
-                                           batch_sh["tokens"],
-                                           replicated(mesh)))
-            lowered = jitted.lower(params_abs, qstate_abs, caches_abs,
-                                   batch_abs["tokens"],
-                                   jax.ShapeDtypeStruct(
-                                       (shape.global_batch,), jnp.int32))
-            compiled = lowered.compile()
+        # per-slot position vector [B]: the continuous-batching ragged
+        # decode step (serving/engine.py) — every slot at its own offset
+        jitted = jax.jit(serve_step,
+                         in_shardings=(params_sh, qstate_sh, caches_sh,
+                                       batch_sh["tokens"],
+                                       replicated(mesh)))
+        lowered = jitted.lower(params_abs, qstate_abs, caches_abs,
+                               batch_abs["tokens"],
+                               jax.ShapeDtypeStruct(
+                                   (shape.global_batch,), jnp.int32))
+        compiled = lowered.compile()
 
     compile_s = time.time() - t0
     hlo = compiled.as_text()

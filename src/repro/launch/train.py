@@ -23,10 +23,12 @@ from __future__ import annotations
 import time
 
 from ..api import RunSpec, build
+from .cache import enable_compile_cache
 
 
 def main() -> None:
     spec = RunSpec.from_args()
+    enable_compile_cache()
     ctx = build(spec)
     comp = ctx.grad_compression()
     if (spec.compression.kind == "int8-wire" and comp.wire
@@ -35,21 +37,20 @@ def main() -> None:
               f"int8-wire to the 2D-sliced exchange (int8-wire-2d)")
     setup = ctx.init_training()
     tcfg = spec.train
-    with ctx.mesh:
-        if tcfg.ckpt_dir and setup.maybe_resume():
-            print(f"resumed from step {setup.start_step}")
-        start = setup.start_step
-        t0 = time.time()
-        for step in range(start, tcfg.steps):
-            m = setup.step(step)
-            if step % max(tcfg.steps // 10, 1) == 0:
-                print(f"step {step}: loss={float(m['loss']):.4f} "
-                      f"ebops={float(m['ebops']):.3g}")
-            if tcfg.ckpt_dir and step and step % tcfg.ckpt_every == 0:
-                # label = steps applied = next step to run; labelling
-                # with `step` would replay an already-applied batch
-                setup.checkpoint(step + 1)
-        print(f"done: {tcfg.steps - start} steps in {time.time()-t0:.1f}s")
+    if tcfg.ckpt_dir and setup.maybe_resume():
+        print(f"resumed from step {setup.start_step}")
+    start = setup.start_step
+    t0 = time.time()
+    for step in range(start, tcfg.steps):
+        m = setup.step(step)
+        if step % max(tcfg.steps // 10, 1) == 0:
+            print(f"step {step}: loss={float(m['loss']):.4f} "
+                  f"ebops={float(m['ebops']):.3g}")
+        if tcfg.ckpt_dir and step and step % tcfg.ckpt_every == 0:
+            # label = steps applied = next step to run; labelling
+            # with `step` would replay an already-applied batch
+            setup.checkpoint(step + 1)
+    print(f"done: {tcfg.steps - start} steps in {time.time()-t0:.1f}s")
 
 
 if __name__ == "__main__":

@@ -288,15 +288,16 @@ class WhisperModel:
             nx, nq["ln_x"] = LayerNorm.apply(lp["ln_x"], lq["ln_x"], h,
                                              mode=mode, aux=a)
             if decode:
-                nq["xattn_kv"] = {}
                 xt, nq["xattn"] = CrossAttention.decode(
                     lp["xattn"], lq["xattn"], nx, ck, cv, mem, cfg, mode,
                     a, ckf=ckf, cvf=cvf)
             else:
-                kh, vh, nq["xattn_kv"] = CrossAttention.kv(
+                kh, vh, kvq = CrossAttention.kv(
                     lp["xattn"], lq["xattn"], memory, cfg, mode, a)
-                xt, nq["xattn"] = CrossAttention.apply(
+                xt, xq = CrossAttention.apply(
                     lp["xattn"], lq["xattn"], nx, kh, vh, cfg, mode, a)
+                # the step hands back qstate in the tree it took
+                nq["xattn"] = {**xq, **kvq}
             h = h + xt.q
             n2, nq["ln2"] = LayerNorm.apply(lp["ln2"], lq["ln2"], h,
                                             mode=mode, aux=a)

@@ -20,7 +20,7 @@ from repro import analysis
 from repro.analysis import (SCALAR_MAX, Collective, ExplicitCollective,
                             ProgramArtifacts, Violation)
 from repro.analysis.rules import run_rules
-from repro.api import RunSpec, build
+from repro.api import MeshSpec, RunSpec, build, build_mesh
 
 multidevice = pytest.mark.skipif(
     jax.device_count() < 8,
@@ -44,10 +44,15 @@ def test_parse_collectives_tuple_and_async():
         "replica_groups={{0,1}}, dimensions={0}",
         "%ag = s8[2,512]{1,0} all-gather-start(%g), replica_groups=[2,4]<=[8]",
         "%f = f32[8]{0} fusion(%all-reduce.169), kind=kLoop",  # operand ref
+        # TPU tiled layouts nest parentheses inside the result type
+        "%all-gather.4 = s8[1024,512]{1,0:T(8,128)(4,1)S(1)} all-gather("
+        "%b), channel_id=1, replica_groups={{0,1},{2,3}}, dimensions={0}",
     ])
     cs = analysis.parse_collectives(hlo)
     assert [(c.kind, c.dtype) for c in cs] == [
-        ("all-to-all", "s8"), ("all-gather", "s8")]
+        ("all-to-all", "s8"), ("all-gather", "s8"), ("all-gather", "s8")]
+    assert cs[2].dims == (1024, 512)
+    assert cs[2].groups == ((0, 1), (2, 3))
     # iota without transpose: [2,4]<=[8] -> rows of consecutive ids
     assert cs[1].groups == ((0, 1, 2, 3), (4, 5, 6, 7))
 
@@ -82,6 +87,11 @@ def test_strip_metadata_removes_location_noise():
     b = 'op(%x), metadata={op_name="g/beta" source_file="b.py"}, calls=%c'
     assert analysis.strip_metadata(a) == analysis.strip_metadata(b)
     assert "alpha" not in analysis.strip_metadata(a)
+    # the stack-frame tables compiled modules open with
+    tables = ('HloModule m\n\nFileNames\n1 "a.py"\n\nFileLocations\n'
+              '1 {file_name_id=1 line=3}\n\nStackFrames\n'
+              '1 {file_location_id=1 parent_frame_id=1}\n\nENTRY %e {}\n')
+    assert analysis.strip_metadata(tables) == "HloModule m\n\n\n\n\nENTRY %e {}\n"
 
 
 def test_input_output_aliases_nested_braces():
@@ -98,14 +108,13 @@ def test_explicit_collectives_through_shard_map():
     """The walker finds a psum written inside a shard_map body, with the
     logical axis name attached (a size-1 axis would be elided at trace
     time, hence the real 2x4 mesh)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(2, 4))
 
     def f(x):
         return jax.lax.psum(x, "data")
 
-    sm = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
+    sm = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
     traced = jax.jit(sm).trace(jnp.zeros((8, 4), jnp.float32))
     (c,) = analysis.explicit_collectives(traced.jaxpr)
     assert c.primitive == "psum" and c.axes == ("data",)
@@ -271,7 +280,7 @@ def test_mesh_layout_is_row_major():
     """crosses_data_axis assumes jax.make_mesh((D, M)) lays device ids
     out row-major (id = d*M + m) — pin that, since every grouping
     classification in the linter rests on it."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(2, 4))
     ids = [[d.id for d in row] for row in mesh.devices]
     assert ids == [[0, 1, 2, 3], [4, 5, 6, 7]]
 

@@ -19,7 +19,8 @@ from hypothesis import strategies as st
 
 from repro.analysis import strip_metadata, train_step_hlo
 from repro.api import (CompressionSpec, GRAD_COMPRESSION_KINDS, MeshSpec,
-                       PrecisionSpec, RunSpec, ServingSpec, build)
+                       PrecisionSpec, RunSpec, ServingSpec, build,
+                       build_mesh)
 
 multidevice = pytest.mark.skipif(
     jax.device_count() < 8,
@@ -43,7 +44,8 @@ def test_default_spec_roundtrip_exact():
        st.integers(min_value=0, max_value=len(GRAD_COMPRESSION_KINDS) - 1),
        st.integers(min_value=0, max_value=2),
        st.integers(min_value=1, max_value=4096),
-       st.floats(min_value=1e-6, max_value=1.0, width=32))
+       st.floats(min_value=float(np.float32(1e-6)), max_value=1.0,
+                 width=32))
 def test_spec_json_roundtrip_property(seed, d, m, comp_i, dtype_i, steps,
                                       lr):
     """RunSpec.from_json(spec.to_json()) == spec for random specs — every
@@ -237,7 +239,7 @@ def _legacy_step_hlo(mesh_str, grad_compression):
     from repro.configs import get
     from repro.data import DataSpec, make_pipeline
     from repro.dist import EFState, collectives, ef_compress, ef_init
-    from repro.dist.axes import AxisRegistry, axis_scope
+    from repro.dist.axes import axis_scope, registry_for_mesh
     from repro.dist.sharding import (batch_sharding, ef_residual_sharding,
                                      replicated, shard_tree)
     from repro.models import model_for
@@ -247,8 +249,8 @@ def _legacy_step_hlo(mesh_str, grad_compression):
     cfg = get("qwen2-0.5b", smoke=True)
     M = model_for(cfg)
     d, m = (int(v) for v in mesh_str.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model"))
-    with axis_scope(AxisRegistry(("data",), "model", d, m)):
+    mesh = build_mesh(MeshSpec.host(d, m))
+    with axis_scope(registry_for_mesh(mesh)):
         params, qstate = M.init(jax.random.PRNGKey(0), cfg)
         opt = adamw_init(params)
         pipe = make_pipeline(DataSpec(kind="lm", batch=4, seq=32,
@@ -292,15 +294,20 @@ def _legacy_step_hlo(mesh_str, grad_compression):
                             {"tokens": batch_sharding(mesh, 4, 2)},
                             replicated(mesh))
             donate = (0, 2)
-            args = [params, qstate, opt, pipe(0), jnp.int32(0)]
+            # the state placed on its shardings, as init_training does
+            args = [*jax.device_put((params, qstate, opt), in_shardings[:3]),
+                    pipe(0), jnp.int32(0)]
             if ef_state is not None:
                 res_sh = (ef_residual_sharding(
                     ef_state.residual, mesh, layout=wire_layout) if wire
                     else shard_tree(ef_state.residual, mesh, "train"))
                 in_shardings += (EFState(residual=res_sh),)
                 donate += (5,)
-                args.append(ef_state)
+                args.append(jax.device_put(ef_state, in_shardings[5]))
+            out_shardings = (in_shardings[:3] + (replicated(mesh),)
+                             + in_shardings[5:])
             jitted = jax.jit(step_fn, in_shardings=in_shardings,
+                             out_shardings=out_shardings,
                              donate_argnums=donate)
             return jitted.lower(*args).compile().as_text()
 

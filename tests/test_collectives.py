@@ -15,6 +15,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.analysis import SCALAR_MAX, parse_collectives
+from repro.api import MeshSpec, build_mesh
 from repro.dist import EFState, ef_compress, ef_init
 from repro.dist.collectives import (data_axis_size, ef_wire_init,
                                     ef_wire_pmean, fp32_allreduce_bytes,
@@ -263,7 +264,7 @@ def test_bytes_model_nibble_halves_payload():
 
 @multidevice
 def test_shard_map_matches_simulate():
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(4, 2))
     assert data_axis_size(mesh) == 4
     tree = _stacked(jax.random.PRNGKey(1))
     from repro.dist.sharding import ef_residual_sharding
@@ -285,7 +286,7 @@ def test_shard_map_matches_simulate_mixed_widths():
     """Mixed per-leaf widths on the real 1D shard_map path: bit-for-bit
     equal to the simulator (pack∘unpack is the identity on in-range int4
     mantissas, so the packed wire changes no delivered value)."""
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(4, 2))
     tree = _stacked(jax.random.PRNGKey(6))
     widths = {"layers": 4, "vec": 8, "scalar": 8}
     from repro.dist.sharding import ef_residual_sharding
@@ -306,7 +307,7 @@ def test_wire_1d_bytes_model_pins_measured_trace():
     the traced collectives must not drift apart."""
     from repro.dist.collectives import record_wire_bytes
     from repro.dist.sharding import ef_residual_sharding
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(4, 2))
     n = data_axis_size(mesh)
     cases = [("layers", "int8", 8, 3), ("layers", "int8", 4, 3),
              ("vec", "int8", 4, 1), ("vec", "bf16", 8, 1)]
@@ -330,7 +331,7 @@ def test_wire_1d_bytes_model_pins_measured_trace():
 def test_wire_vjp_composes():
     """value_and_grad through the collective: the backward is the
     transpose of an uncompressed shard mean (cotangent / n per shard)."""
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(4, 2))
     tree = {"w": jax.random.normal(jax.random.PRNGKey(2), (4, 6, 5))}
     with mesh:
         val, grads = jax.value_and_grad(
@@ -358,7 +359,7 @@ def test_compressed_step_tracks_post_reduce():
     loss = lambda out, b: softmax_xent(out, b["y"])
     pipe = make_pipeline(DataSpec(kind="jet", batch=256))
     tc = TrainConfig(steps=20, lr=3e-3, beta0=1e-7, beta1=1e-6)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(4, 2))
     n = collectives.data_axis_size(mesh)
 
     # wire_layout pinned to "1d": this test drives the 1D collective (the
@@ -407,7 +408,7 @@ def test_compressed_step_hlo_moves_int8():
     loss = lambda out, b: softmax_xent(out, b["y"])
     pipe = make_pipeline(DataSpec(kind="jet", batch=256))
     tc = TrainConfig(steps=8, lr=3e-3)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(4, 2))
     n = collectives.data_axis_size(mesh)
     step = make_train_step(fwd, loss, tc, reduce="compressed", mesh=mesh,
                            wire_layout="1d")
@@ -453,7 +454,7 @@ def test_fused_matches_legacy_1d(kind):
     concatenated pmax/all_to_all/all_gather per bucket) delivers the SAME
     bits as the legacy per-leaf path and the simulator."""
     from repro.dist.sharding import ef_residual_sharding
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(4, 2))
     tree = _stacked(jax.random.PRNGKey(20))
     with mesh:
         placed = jax.device_put(tree, ef_residual_sharding(tree, mesh))
@@ -475,7 +476,7 @@ def test_fused_multi_bucket_matches_simulator():
     bucket (odd chunk tails included) — still bit-for-bit the simulator,
     with mixed per-leaf widths riding the nibble wire."""
     from repro.dist.sharding import ef_residual_sharding
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(4, 2))
     tree = _stacked(jax.random.PRNGKey(21))
     widths = {"layers": 4, "vec": 8, "scalar": 8}
     from repro.dist.collectives import _bucket_leaves, _WIRE_BUCKET_BYTES
@@ -501,7 +502,7 @@ def test_fused_records_same_bytes_as_legacy():
     bucket schedule) — the fusion moves launches, not bytes."""
     from repro.dist.collectives import record_wire_bytes
     from repro.dist.sharding import ef_residual_sharding
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(4, 2))
     tree = _stacked(jax.random.PRNGKey(22))
     widths = {"layers": 4, "vec": 8, "scalar": 8}
     with mesh:

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.api import MeshSpec, build_mesh
 from repro.dist import EFState, ef_compress, ef_init
 from repro.dist.axes import (AxisRegistry, axis_scope, constrain,
                              get_model_size)
@@ -111,7 +112,7 @@ def test_shard_tree_on_real_mesh():
     """On the 1x1 host mesh everything replicates (axis size 1 never
     shards) but the NamedSharding tree must build and jit-apply."""
     from jax.sharding import NamedSharding
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(1, 1))
     tree = {"kernel": {"w": jnp.zeros((8, 16)), "f": jnp.zeros((1, 16))}}
     sh = shard_tree(tree, mesh, "train")
     assert all(isinstance(s, NamedSharding)

@@ -375,7 +375,14 @@ def test_prefix_reuse_across_recycled_slots_matches_cold():
 
 
 def test_qmatmul_backend_interpret_default():
-    from repro.kernels.qmatmul.ops import default_interpret
-    # this suite runs on CPU: the Pallas kernel must select interpret mode
-    assert jax.default_backend() == "cpu"
-    assert default_interpret() is True
+    """Every kernel family's ``interpret=None`` resolves per backend:
+    compiled on TPU, interpreted elsewhere — hgq_quantize included."""
+    from repro.analysis.jaxpr import iter_eqns
+    from repro.kernels import hgq_quantize
+    from repro.kernels.backend import default_interpret
+    assert default_interpret() == (jax.default_backend() != "tpu")
+    traced = jax.make_jaxpr(lambda x: hgq_quantize(x, jnp.float32(3.0)))(
+        jnp.ones((8, 128)))
+    modes = {bool(e.params["interpret"]) for e in iter_eqns(traced)
+             if e.primitive.name == "pallas_call"}
+    assert modes == {default_interpret()}

@@ -19,6 +19,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.analysis import SCALAR_MAX, parse_collectives
+from repro.api import MeshSpec, build_mesh
 from repro.dist import EFState, ef_init, ef_compress
 from repro.dist.collectives import (data_axis_size, ef_wire2d_init,
                                     ef_wire_init, ef_wire_pmean_2d,
@@ -265,7 +266,7 @@ def test_wire2d_bytes_beat_1d_with_tp_replication():
 @multidevice
 @pytest.mark.parametrize("D,M", [(2, 4), (4, 2)])
 def test_wire2d_shard_map_matches_simulate(D, M):
-    mesh = jax.make_mesh((D, M), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(D, M))
     assert data_axis_size(mesh) == D and model_axis_size(mesh) == M
     tree = _stacked(jax.random.PRNGKey(1), D)
     res = _init_res(tree, D, M)
@@ -288,7 +289,7 @@ def test_wire2d_shard_map_matches_simulate_mixed_widths(D, M):
     """The acceptance contract for mixed widths: the real 2D shard_map
     collective is bit-for-bit equal to its simulator when leaves ride
     different wire widths."""
-    mesh = jax.make_mesh((D, M), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(D, M))
     tree = _stacked(jax.random.PRNGKey(10), D)
     widths = {"w": 4, "layers": 4, "vec": 8, "scalar": 8}
     res = _init_res(tree, D, M)
@@ -311,7 +312,7 @@ def test_wire2d_leaf_bytes_pins_measured_trace(kind, bits):
     and bf16 (the satellite contract: the byte model may not drift from
     the traced collectives)."""
     D, M = 2, 4
-    mesh = jax.make_mesh((D, M), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(D, M))
     full = _stacked(jax.random.PRNGKey(11), D)
     with mesh:
         for name in ("w", "layers", "vec", "scalar"):
@@ -335,7 +336,7 @@ def test_wire2d_pure_tp_takes_sliced_path_no_data_exchange():
     """--mesh 1xM (pure TP): the sliced path runs — and the trace emits
     NO data-axis exchange (no all_to_all, no data all_gather), only the
     model-axis rematerialization plus the scale pmax."""
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(1, 8))
     tree = _stacked(jax.random.PRNGKey(2), 1)
     res = _init_res(tree, 1, 8)
     with mesh:
@@ -371,7 +372,7 @@ def test_wire2d_pure_tp_train_step_selected():
     loss = lambda out, b: softmax_xent(out, b["y"])
     pipe = make_pipeline(DataSpec(kind="jet", batch=64))
     tc = TrainConfig(steps=4, lr=3e-3)
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(1, 8))
     step = make_train_step(fwd, loss, tc, reduce="compressed", mesh=mesh)
     with mesh:
         ec = EFState(residual=ef_wire2d_init(p0, 1, 8))
@@ -389,7 +390,7 @@ def test_wire2d_pure_tp_train_step_selected():
 
 @multidevice
 def test_wire2d_vjp_composes():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(2, 4))
     tree = {"w": jax.random.normal(jax.random.PRNGKey(2), (2, 6, 8))}
     res = ef_wire2d_init({"w": tree["w"][0]}, 2, 4)
     with mesh:
@@ -427,7 +428,7 @@ def test_compressed_2d_step_tracks_post_reduce():
 
     p0, q0, fwd, loss, pipe = _jet_setup()
     tc = TrainConfig(steps=20, lr=3e-3, beta0=1e-7, beta1=1e-6)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(2, 4))
     step_c = make_train_step(fwd, loss, tc, reduce="compressed", mesh=mesh,
                              wire_layout="2d")
     step_r = make_train_step(
@@ -462,7 +463,7 @@ def test_compressed_2d_step_hlo_moves_int8():
     p0, q0, fwd, loss, pipe = _jet_setup()
     tc = TrainConfig(steps=8, lr=3e-3)
     D, M = 2, 4
-    mesh = jax.make_mesh((D, M), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(D, M))
     step = make_train_step(fwd, loss, tc, reduce="compressed", mesh=mesh)
     with mesh:
         ec = EFState(residual=ef_wire2d_init(p0, D, M))
@@ -490,7 +491,7 @@ def test_wire2d_resume_exact(tmp_path):
     p0, q0, fwd, loss, pipe = _jet_setup()
     tc = TrainConfig(steps=8, lr=3e-3)
     D, M = 2, 4
-    mesh = jax.make_mesh((D, M), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(D, M))
     step = jax.jit(make_train_step(fwd, loss, tc, reduce="compressed",
                                    mesh=mesh))
     with mesh:
@@ -529,7 +530,7 @@ def test_wire2d_fused_matches_legacy(D, M):
     per-bucket a2a/gather) is bit-for-bit the legacy per-leaf path and
     the simulator — on both DxM shapes AND the pure-TP 1x8 mesh, with
     mixed widths, at the default and a bucket-per-leaf budget."""
-    mesh = jax.make_mesh((D, M), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(D, M))
     tree = _stacked(jax.random.PRNGKey(30), D)
     widths = {"w": 4, "layers": 4, "vec": 8, "scalar": 8}
     res = _init_res(tree, D, M)
@@ -559,7 +560,7 @@ def test_wire2d_fused_records_same_bytes_as_legacy():
     (bf16 and int8, stacked and flat leaves) — bucketing changes launch
     count, never bytes."""
     D, M = 2, 4
-    mesh = jax.make_mesh((D, M), ("data", "model"))
+    mesh = build_mesh(MeshSpec.host(D, M))
     tree = _stacked(jax.random.PRNGKey(31), D)
     res = _init_res(tree, D, M)
     with mesh:
