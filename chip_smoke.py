@@ -20,7 +20,9 @@ One chip:
   (``tpu_custom_call``), so a silent fallback to the jnp reference fails.
 
 Four chips: the compressed gradient exchange over the data axis (the 2D
-int8 wire) against the fp32 all-reduce, 3 steps each on a 2x2 mesh.
+int8 wire) against the fp32 all-reduce, 3 steps each on a 2x2 mesh and
+one repeated batch; after steps 1 and 2 the two losses must agree within
+``WIRE_BAND`` of the fp32 loss's drop from step 0.
 
 Every phase asserts its outcome and any failure exits non-zero.  On a
 machine whose JAX sees no TPU the script refuses before doing any work.
@@ -46,10 +48,12 @@ PROMPT_LENS = (32, 77, 128, 190, 256, 45)
 MAX_NEW = 16
 SLOTS = 4                      # fewer slots than requests: slots recycle
 MAX_LEN = 512
-# int8-wire-2d vs fp32 losses, |a - b| <= WIRE_BAND * |b|: the first
-# step sees identical weights (only summation order differs); the later
-# ones see one or two Adam updates from int8 error-feedback gradients.
-WIRE_BAND = 0.01
+# --four-chips trains on one repeated batch, so the loss moves by learning
+# alone; after steps 1 and 2, |wire - fp32| <= WIRE_BAND x fp32's drop
+# from step 0.  4 host CPU devices, smoke config: 0.011 and 0.019 with the
+# real wire; 1.0 with a wire that delivers zeros, 0.20 at step 2 with
+# one that delivers half.
+WIRE_BAND = 0.1
 
 
 def log(msg: str) -> None:
@@ -91,14 +95,16 @@ def _spec(seed: int, **kw):
         train=dataclasses.replace(base.train, steps=STEPS), **kw)
 
 
-def _train(spec, tag: str):
+def _train(spec, tag: str, one_batch: bool = False):
     """Build, compile and run STEPS train steps of ``spec``: (losses,
-    compiled HLO text)."""
-    import jax
+    compiled HLO text).  ``one_batch`` feeds batch 0 at every step."""
     import jax.numpy as jnp
     from repro.api import build
 
     setup = build(spec).init_training()
+    if one_batch:
+        first = setup.pipeline(0)
+        setup.pipeline = lambda step: first
     args = [setup.params, setup.qstate, setup.opt, setup.pipeline(0),
             jnp.int32(0)]
     if setup.ef_state is not None:
@@ -219,18 +225,24 @@ def four_chip_phase(seed: int) -> None:
     for kind in ("none", "int8-wire-2d"):
         losses[kind], hlo[kind] = _train(
             _spec(seed, mesh=MeshSpec.host(2, 2),
-                  compression=CompressionSpec(kind=kind)), f"2x2 {kind}")
+                  compression=CompressionSpec(kind=kind)), f"2x2 {kind}",
+            one_batch=True)
     wire = {(c.kind, c.dtype)
             for c in parse_collectives(hlo["int8-wire-2d"])}
     log(f"2x2 int8-wire-2d: s8 collectives "
         f"{sorted(k for k, d in wire if d == 's8')}")
     check({("all-to-all", "s8"), ("all-gather", "s8")} <= wire,
           f"2x2 int8-wire-2d: no s8 all-to-all/all-gather in {wire}")
-    for step, (a, b) in enumerate(zip(losses["int8-wire-2d"],
-                                      losses["none"])):
-        log(f"2x2 step {step}: int8-wire-2d {a:.6f} fp32 {b:.6f} "
-            f"rel diff {abs(a - b) / abs(b):.3g} (band {WIRE_BAND})")
-        check(abs(a - b) <= WIRE_BAND * abs(b),
+    ref = losses["none"]
+    for step in range(1, STEPS):
+        a, b = losses["int8-wire-2d"][step], ref[step]
+        drop = ref[0] - b
+        check(drop > 0, f"2x2 step {step}: fp32 loss did not drop "
+                        f"({ref[0]} -> {b})")
+        log(f"2x2 step {step}: int8-wire-2d {a!r} fp32 {b!r}; gap "
+            f"{abs(a - b) / drop!r} of fp32's drop {drop!r} "
+            f"(band {WIRE_BAND})")
+        check(abs(a - b) <= WIRE_BAND * drop,
               f"2x2 step {step}: losses {a} and {b} outside the band")
 
 

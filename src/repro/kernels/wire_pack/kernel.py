@@ -4,7 +4,7 @@ Phase 1 of the int8-on-the-wire exchange is three elementwise sweeps in
 the reference path — grid-exponent from the pmax'd amax, saturating
 quantize, nibble pack — plus a fourth to materialize the error-feedback
 residual.  Each kernel here fuses its stage into one VMEM pass over
-(block_rows, block_cols) tiles — columns are tiled too, because one
+(block_rows, DEFAULT_BLOCK_COLS) tiles — columns are tiled too, because one
 bucket row of a full-size model is far wider than VMEM:
 
   * ``wire_quantize_rows``  — amax -> 2^-f grid -> round/clip -> int8
@@ -91,30 +91,29 @@ def _dequant_kernel(q_ref, s_ref, o_ref, *, mul, n):
     o_ref[...] = q_ref[...].astype(jnp.float32) * mul * s_ref[...] / n
 
 
-def _tiles(R: int, C: int, block_rows: int, block_cols: int):
+def _tiles(R: int, C: int, block_rows: int):
     """(grid, [br, bc] tile spec, [br, 1] row-column spec) over an
-    [R, C] array; edge blocks may be partial (elementwise kernels only
-    write in bounds)."""
-    br, bc = min(block_rows, R), min(block_cols, C)
+    [R, C] array, bc <= DEFAULT_BLOCK_COLS; edge blocks may be partial
+    (elementwise kernels only write in bounds)."""
+    br, bc = min(block_rows, R), min(DEFAULT_BLOCK_COLS, C)
     tile = pl.BlockSpec((br, bc), lambda i, j: (i, j))
     col = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
     return (pl.cdiv(R, br), pl.cdiv(C, bc)), tile, col
 
 
-_STATIC = ("block_rows", "block_cols", "interpret")
+_STATIC = ("block_rows", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=("bits",) + _STATIC)
 def wire_quantize_rows(rows: jax.Array, amax: jax.Array, *, bits: int = 8,
                        block_rows: int = DEFAULT_BLOCK_ROWS,
-                       block_cols: int = DEFAULT_BLOCK_COLS,
                        interpret: Optional[bool] = None):
     """[L, P] fp32 rows + [L] amax -> (int8 [L, P], scale [L],
     residual fp32 [L, P]); P must be lane-aligned (ops.py pads)."""
     from ..qmatmul.ops import mantissa_max
     L, P = rows.shape
     assert P % LANE == 0, f"cols {P} must be lane-aligned"
-    grid, tile, col = _tiles(L, P, block_rows, block_cols)
+    grid, tile, col = _tiles(L, P, block_rows)
     kern = functools.partial(_quantize_rows_kernel,
                              qmax=float(mantissa_max(bits)))
     q, s, r = pl.pallas_call(
@@ -133,13 +132,12 @@ def wire_quantize_rows(rows: jax.Array, amax: jax.Array, *, bits: int = 8,
 @functools.partial(jax.jit, static_argnames=("bits",) + _STATIC)
 def wire_quantize_sflat(x: jax.Array, s: jax.Array, *, bits: int = 8,
                         block_rows: int = DEFAULT_BLOCK_ROWS,
-                        block_cols: int = DEFAULT_BLOCK_COLS,
-                        interpret: Optional[bool] = None):
+                         interpret: Optional[bool] = None):
     """[R, C] fp32 + per-position [R, C] scale -> (int8, residual)."""
     from ..qmatmul.ops import mantissa_max
     R, C = x.shape
     assert C % LANE == 0, f"cols {C} must be lane-aligned"
-    grid, tile, _ = _tiles(R, C, block_rows, block_cols)
+    grid, tile, _ = _tiles(R, C, block_rows)
     kern = functools.partial(_quantize_sflat_kernel,
                              qmax=float(mantissa_max(bits)))
     return pl.pallas_call(
@@ -156,14 +154,13 @@ def wire_quantize_sflat(x: jax.Array, s: jax.Array, *, bits: int = 8,
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def wire_pack_rows(lo: jax.Array, hi: jax.Array, *,
                    block_rows: int = DEFAULT_BLOCK_ROWS,
-                   block_cols: int = DEFAULT_BLOCK_COLS,
                    interpret: Optional[bool] = None) -> jax.Array:
     """[R, C] int4-range mantissas of the even (``lo``) and odd (``hi``)
     columns -> [R, C] packed bytes ``lo & 0xF | hi << 4``; C must be
     lane-aligned."""
     R, C = lo.shape
     assert C % LANE == 0, f"cols {C} must be lane-aligned"
-    grid, tile, _ = _tiles(R, C, block_rows, block_cols)
+    grid, tile, _ = _tiles(R, C, block_rows)
     return pl.pallas_call(
         _pack_kernel,
         grid=grid,
@@ -177,13 +174,12 @@ def wire_pack_rows(lo: jax.Array, hi: jax.Array, *,
 @functools.partial(jax.jit, static_argnames=("shift", "n") + _STATIC)
 def wire_dequant_rows(q: jax.Array, s: jax.Array, *, shift: int, n: int,
                       block_rows: int = DEFAULT_BLOCK_ROWS,
-                      block_cols: int = DEFAULT_BLOCK_COLS,
-                      interpret: Optional[bool] = None) -> jax.Array:
+                         interpret: Optional[bool] = None) -> jax.Array:
     """[R, C] mantissa sums + [R, C] scale -> fp32 ``q * 2^shift * s / n``
     (the phase-2 delivered-mean decode) in one pass."""
     R, C = q.shape
     assert C % LANE == 0, f"cols {C} must be lane-aligned"
-    grid, tile, _ = _tiles(R, C, block_rows, block_cols)
+    grid, tile, _ = _tiles(R, C, block_rows)
     kern = functools.partial(_dequant_kernel, mul=float(2 ** shift), n=n)
     return pl.pallas_call(
         kern,
