@@ -14,10 +14,11 @@ One chip:
 * serve — three continuous-batching engines (fp, ``packed`` weights on
   ``kernels/qmatmul``, and the 4-bit ``kv_cache="plan"`` of
   ``examples/specs/serving_kv_plan.json`` on ``kernels/kv_dequant``)
-  each serve 6 greedy requests with 32-256 token prompts.  The fp engine
-  must equal ``serving.generate()`` token for token, and the packed and
-  plan decode programs must hold their Pallas kernels
-  (``tpu_custom_call``), so a silent fallback to the jnp reference fails.
+  each serve 6 greedy requests with 32-256 token prompts.  The fp
+  engine is held to the model's one-shot forward (``_check_fp``) and
+  compared with ``serving.generate()``; the packed and plan decode
+  programs must hold their Pallas kernels (``tpu_custom_call``), so a
+  silent fallback to the jnp reference fails.
 
 Four chips: the compressed gradient exchange over the data axis (the 2D
 int8 wire) against the fp32 all-reduce, 3 steps each on a 2x2 mesh and
@@ -50,10 +51,25 @@ SLOTS = 4                      # fewer slots than requests: slots recycle
 MAX_LEN = 512
 # --four-chips trains on one repeated batch, so the loss moves by learning
 # alone; after steps 1 and 2, |wire - fp32| <= WIRE_BAND x fp32's drop
-# from step 0.  4 host CPU devices, smoke config: 0.011 and 0.019 with the
-# real wire; 1.0 with a wire that delivers zeros, 0.20 at step 2 with
-# one that delivers half.
-WIRE_BAND = 0.1
+# from step 0.  At the published vocab the int8 wire itself trails fp32:
+# 0.036 and 0.324 on a v5e 2x2 at full widths, 0.113 and 0.176 on 4 host
+# CPU devices at d_model 56, vocab 151,936, seq 64.  A wire that delivers
+# zeros reads 1.0 there.  One that delivers half reads as the real wire
+# (Adam is nearly scale-blind), which no loss band can catch.
+WIRE_BAND = 0.5
+# The fp engine's tokens against the model's one-shot forward, teacher-
+# forced on the engine's own output.  On the CPU the two agree bit for bit
+# (tests/test_serving_engine.py).  On a v5e they need not: the engine's
+# chunked prefill and batched decode reduce in other orders than the
+# one-shot forward, an HGQ quantizer can turn a last-bit difference into a
+# whole grid step, and a near tie among 151,936 logits then flips.  So
+# every served token must be within TIE_BAND x the row's logit std of the
+# reference's top logit, and at most TIE_SHARE of them may sit below it.
+# One v5e, seed 0: 4 of 96 tokens below it, by at most 0.0074 std; with
+# the decode position planted one late, gaps of up to 1.66 std (CPU,
+# smoke config).
+TIE_BAND = 0.1
+TIE_SHARE = 0.1
 
 
 def log(msg: str) -> None:
@@ -174,29 +190,80 @@ def _serve(ctx, params, qstate, tag: str):
     return eng, reqs
 
 
-def serve_phase(seed: int) -> None:
+def _reference_logits(ctx, params, qstate, reqs):
+    """Per request, the reference logits [MAX_NEW, V] that predict each
+    served token: the model's one-shot forward at batch 1 over prompt +
+    out[:-1] (no slots, chunks or ragged positions), padded to one length
+    so that it compiles once."""
     import jax
     import jax.numpy as jnp
-    from repro.api import RunSpec, ServingSpec, build
+    import numpy as np
+    model, cfg = ctx.model, ctx.cfg
+    T = max(len(r.prompt) for r in reqs) + MAX_NEW
+    fwd = jax.jit(lambda p, q, c, t: model.decode_step(
+        p, q, c, t, jnp.int32(0), cfg)[0])
+    out = []
+    for r in reqs:
+        toks = r.prompt + r.out[:-1]
+        cache = model.init_cache(cfg, 1, T, ring_slack=T)
+        logits = fwd(params, qstate, cache,
+                     jnp.asarray([toks + [0] * (T - len(toks))], jnp.int32))
+        P = len(r.prompt)
+        out.append(np.asarray(logits[0, P - 1:P - 1 + MAX_NEW], np.float32))
+    return out
+
+
+def _check_fp(ctx, params, qstate) -> None:
+    """Serve the requests on the fp engine and hold every token to the
+    one-shot reference within TIE_BAND; where the engine and
+    ``generate()`` part, both tokens must be near-ties there."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
     from repro.serving import generate
+
+    # float32 matmul passes: the TPU's default single bf16 pass would add
+    # its own rounding, which differs with the operands' shapes
+    with jax.default_matmul_precision("float32"):
+        _, reqs = _serve(ctx, params, qstate, "serve fp")
+        refs = _reference_logits(ctx, params, qstate, reqs)
+        gens = [[int(t) for t in generate(
+            ctx.model, params, qstate, ctx.cfg,
+            jnp.asarray([r.prompt], jnp.int32), r.max_new,
+            cache_len=MAX_LEN)[0]] for r in reqs]
+    below = 0
+    for i, (r, L, gen) in enumerate(zip(reqs, refs, gens)):
+        top, std = L.max(-1), L.std(-1)
+        gap = (top - L[np.arange(MAX_NEW), r.out]) / std
+        agree = next((t for t in range(MAX_NEW) if r.out[t] != gen[t]),
+                     MAX_NEW)
+        log(f"serve fp: request {i}: engine == generate() for the first "
+            f"{agree} of {MAX_NEW} tokens; {int((gap > 0).sum())} engine "
+            f"tokens below the reference's top logit, by at most "
+            f"{float(gap.max())!r} logit std (band {TIE_BAND})")
+        check(gap.max() <= TIE_BAND,
+              f"serve fp: request {i}: engine {r.out} is off the reference "
+              f"(gaps {gap.tolist()})")
+        if agree < MAX_NEW:
+            g = (top[agree] - L[agree, gen[agree]]) / std[agree]
+            check(g <= TIE_BAND,
+                  f"serve fp: request {i}: generate() {gen} is off the "
+                  f"reference at token {agree} by {g} logit std")
+        below += int((gap > 0).sum())
+    n = len(reqs) * MAX_NEW
+    check(below <= TIE_SHARE * n,
+          f"serve fp: {below} of {n} tokens below the reference's top "
+          f"logit (at most {TIE_SHARE} allowed)")
+    log(f"serve fp: every token within {TIE_BAND} logit std of the "
+        f"reference; {below} of {n} below its top logit")
+
+
+def serve_phase(seed: int) -> None:
+    from repro.api import RunSpec, ServingSpec, build
 
     fp = build(_spec(seed, serving=ServingSpec(slots=SLOTS)))
     params, qstate = fp.init_state()
-
-    # fp engine == generate() token for token.  Both run at float32
-    # matmul precision: the engine prefills in chunks and generate() in
-    # one call, and at the TPU's default bf16 passes that reordering
-    # alone can flip a greedy near-tie among 151,936 logits.
-    with jax.default_matmul_precision("float32"):
-        _, reqs = _serve(fp, params, qstate, "serve fp")
-        for r in reqs:
-            ref = generate(fp.model, params, qstate, fp.cfg,
-                           jnp.asarray([r.prompt], jnp.int32), r.max_new,
-                           cache_len=MAX_LEN)
-            ref = [int(t) for t in ref[0]]
-            check(r.out == ref,
-                  f"serve fp: engine {r.out} != generate() {ref}")
-    log("serve fp: engine == generate() token for token on all requests")
+    _check_fp(fp, params, qstate)
 
     packed = build(_spec(seed, serving=ServingSpec(slots=SLOTS,
                                                    packed=True)))
