@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import AxisType
 
+from .. import obs
 from ..configs import get as get_config
 from ..data import make_pipeline
 from ..data.synthetic import DataSpec
@@ -104,16 +105,20 @@ class TrainSetup:
         self.start_step = 0
 
     def step(self, step: int) -> Dict[str, jax.Array]:
-        batch = self.pipeline(step)
-        if self.ef_state is not None:
-            (self.params, self.qstate, self.opt, metrics,
-             self.ef_state) = self.jitted(self.params, self.qstate,
-                                          self.opt, batch,
-                                          jnp.int32(step), self.ef_state)
-        else:
-            self.params, self.qstate, self.opt, metrics = self.jitted(
-                self.params, self.qstate, self.opt, batch,
-                jnp.int32(step))
+        with obs.span("train.step"):
+            with obs.span("train.batch"):
+                batch = self.pipeline(step)
+            with obs.span("train.dispatch"):
+                if self.ef_state is not None:
+                    (self.params, self.qstate, self.opt, metrics,
+                     self.ef_state) = self.jitted(
+                        self.params, self.qstate, self.opt, batch,
+                        jnp.int32(step), self.ef_state)
+                else:
+                    self.params, self.qstate, self.opt, metrics = \
+                        self.jitted(self.params, self.qstate, self.opt,
+                                    batch, jnp.int32(step))
+        obs.count("train.steps")
         return metrics
 
     # --------------------- checkpointing / resume ----------------------

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..dist.axes import axis_scope, get_axes
 from ..dist.perf import (compute_dtype_scope, get_compute_dtype,
                          packed_matmul)
@@ -79,9 +80,11 @@ class RequestHandle:
     """Admission receipt for one submitted request: what ``submit``
     returns and ``Engine.tokens`` reads from.  Truthy (so legacy
     ``if eng.submit(req):`` call sites keep working — a full engine
-    returns ``None``); carries the request plus the incremental-read
-    cursor."""
+    returns ``None``); carries the request, the engine's request number
+    ``rid`` (the ``rid`` of its ``engine.submit`` span) and the
+    incremental-read cursor."""
     request: Request
+    rid: int = 0
     _cursor: int = 0
 
     @property
@@ -173,13 +176,16 @@ class Engine:
         self.slot_pos = np.zeros(batch_slots, np.int32)   # cache fill level
         self._next_tok = np.zeros(batch_slots, np.int32)  # next decode input
         self._key = jax.random.PRNGKey(seed)
+        self._rid = 0                                     # requests admitted
         self._build()
 
     # ------------------------------------------------------------------
     def _build(self) -> None:
         model, cfg, kv_bits = self.model, self.cfg, self.kv_bits
 
-        def decode(p, q, c, tok, pos, key, temp, topk, enable):
+        # each program's name (``jit_engine_decode`` ...) is how a
+        # profile's module list, and the benchmark reading it, finds it
+        def engine_decode(p, q, c, tok, pos, key, temp, topk, enable):
             if kv_bits is None:
                 logits, c = model.decode_step(p, q, c, tok, pos, cfg)
             else:
@@ -187,7 +193,7 @@ class Engine:
                                               kv_bits=kv_bits)
             return _sample(logits[:, -1], key, temp, topk, enable), c
 
-        def prefill(p, q, cs, tok, pos):
+        def engine_prefill(p, q, cs, tok, pos):
             if kv_bits is None:
                 return model.decode_step(p, q, cs, tok, pos, cfg)
             return model.decode_step(p, q, cs, tok, pos, cfg,
@@ -197,15 +203,19 @@ class Engine:
         # XLA aliases it in place instead of copying the full KV/state tree
         # every decoded token (self.caches is reassigned from the result).
         # _prefill must NOT donate: its first cs is the reused _fresh_slot.
-        self._decode = jax.jit(decode, static_argnums=(8,),
-                               donate_argnums=(2,))
-        self._prefill = jax.jit(prefill)
-        self._write_slot = jax.jit(
-            lambda c, cs, s: jax.tree_util.tree_map(
+        def engine_splice(c, cs, s):
+            return jax.tree_util.tree_map(
                 lambda a, u: jax.lax.dynamic_update_slice_in_dim(
-                    a, u, s, axis=1), c, cs),
-            donate_argnums=(0,))
-        self._sample1 = jax.jit(_sample, static_argnums=(4,))
+                    a, u, s, axis=1), c, cs)
+
+        def engine_first_token(logits, key, temp, topk, enable):
+            return _sample(logits, key, temp, topk, enable)
+
+        self._decode = jax.jit(engine_decode, static_argnums=(8,),
+                               donate_argnums=(2,))
+        self._prefill = jax.jit(engine_prefill)
+        self._write_slot = jax.jit(engine_splice, donate_argnums=(0,))
+        self._sample1 = jax.jit(engine_first_token, static_argnums=(4,))
 
     def _run(self, fn, *args):
         """Call a jitted function under this engine's trace-time snapshot
@@ -264,9 +274,12 @@ class Engine:
         while start < plen:
             n = C if plen - start >= C else \
                 1 << ((plen - start).bit_length() - 1)
-            tok = jnp.asarray([prompt[start:start + n]], jnp.int32)
-            logits, cs = self._run(self._prefill, self.p, self.q, cs, tok,
-                                   jnp.int32(start))
+            with obs.span("engine.prefill_chunk", start=start, n=n):
+                tok = jnp.asarray([prompt[start:start + n]], jnp.int32)
+                logits, cs = self._run(self._prefill, self.p, self.q, cs,
+                                       tok, jnp.int32(start))
+            obs.count("engine.prefill_chunks")
+            obs.count("engine.prefill_tokens", n)
             start += n
             if start >= plen:
                 last_logits = logits[:, -1]
@@ -286,29 +299,40 @@ class Engine:
             raise ValueError(
                 f"need prompt >= 1 ({plen}), max_new >= 1 ({req.max_new}), "
                 f"and prompt + max_new <= max_len ({self.max_len})")
-        key = tuple(req.prompt) if self.prefix_reuse else None
-        if key is not None and key in self._prefix_cache:
-            # exact-prompt reuse: the cached slice is an immutable jax
-            # value (prefill never donates), so splicing it again is safe
-            cs, last_logits = self._prefix_cache.pop(key)
-            self._prefix_cache[key] = (cs, last_logits)   # LRU refresh
-        else:
-            cs, last_logits = self._prefill_prompt(req.prompt)
-            if key is not None:
-                self._prefix_cache[key] = (cs, last_logits)
-                while len(self._prefix_cache) > self._prefix_cap:
-                    self._prefix_cache.pop(next(iter(self._prefix_cache)))
-        self.caches = self._write_slot(self.caches, cs, jnp.int32(slot))
-        sc = self._sampling(req)
-        first = self._run(
-            self._sample1, last_logits, self._split_key(),
-            jnp.asarray([sc.temperature], jnp.float32),
-            jnp.asarray([sc.top_k], jnp.int32), sc.temperature > 0)
-        self.slot_req[slot] = req
-        self.slot_pos[slot] = plen
-        self._next_tok[slot] = int(first[0])
-        self._record(slot, int(first[0]))
-        return RequestHandle(req)
+        self._rid += 1
+        with obs.span("engine.submit", rid=self._rid, prompt=plen,
+                      slot=slot):
+            key = tuple(req.prompt) if self.prefix_reuse else None
+            if key is not None and key in self._prefix_cache:
+                # exact-prompt reuse: the cached slice is an immutable jax
+                # value (prefill never donates), so splicing it again is
+                # safe
+                cs, last_logits = self._prefix_cache.pop(key)
+                self._prefix_cache[key] = (cs, last_logits)  # LRU refresh
+                obs.count("engine.prefix_hits")
+            else:
+                cs, last_logits = self._prefill_prompt(req.prompt)
+                if key is not None:
+                    self._prefix_cache[key] = (cs, last_logits)
+                    while len(self._prefix_cache) > self._prefix_cap:
+                        self._prefix_cache.pop(
+                            next(iter(self._prefix_cache)))
+            with obs.span("engine.splice"):
+                self.caches = self._write_slot(self.caches, cs,
+                                               jnp.int32(slot))
+            sc = self._sampling(req)
+            with obs.span("engine.first_token"):
+                first = self._run(
+                    self._sample1, last_logits, self._split_key(),
+                    jnp.asarray([sc.temperature], jnp.float32),
+                    jnp.asarray([sc.top_k], jnp.int32), sc.temperature > 0)
+                tok = int(first[0])
+            self.slot_req[slot] = req
+            self.slot_pos[slot] = plen
+            self._next_tok[slot] = tok
+            self._record(slot, tok)
+        obs.count("engine.admitted")
+        return RequestHandle(req, rid=self._rid)
 
     def _record(self, slot: int, token: int) -> None:
         """Append a sampled token; finish + recycle the slot on EOS/len."""
@@ -318,6 +342,7 @@ class Engine:
                 len(req.out) >= req.max_new:
             req.done = True
             self.slot_req[slot] = None
+            obs.count("engine.finished")
 
     def step(self) -> None:
         """One engine tick: a single jitted ragged decode step advancing
@@ -326,24 +351,33 @@ class Engine:
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         if not active:
             return
-        tok = jnp.asarray(self._next_tok, jnp.int32)[:, None]
-        pos = jnp.asarray(self.slot_pos, jnp.int32)
-        temp = jnp.asarray(
-            [self._sampling(r).temperature if r else 0.0
-             for r in self.slot_req], jnp.float32)
-        topk = jnp.asarray(
-            [self._sampling(r).top_k if r else 0 for r in self.slot_req],
-            jnp.int32)
-        enable = any(self._sampling(self.slot_req[i]).temperature > 0
-                     for i in active)
-        nxt, self.caches = self._run(self._decode, self.p, self.q,
-                                     self.caches, tok, pos,
-                                     self._split_key(), temp, topk, enable)
-        nxt = np.asarray(nxt)
-        for i in active:
-            self.slot_pos[i] += 1
-            self._next_tok[i] = nxt[i]
-            self._record(i, int(nxt[i]))
+        with obs.span("engine.step", active=len(active)):
+            with obs.span("engine.step.inputs"):
+                tok = jnp.asarray(self._next_tok, jnp.int32)[:, None]
+                pos = jnp.asarray(self.slot_pos, jnp.int32)
+                temp = jnp.asarray(
+                    [self._sampling(r).temperature if r else 0.0
+                     for r in self.slot_req], jnp.float32)
+                topk = jnp.asarray(
+                    [self._sampling(r).top_k if r else 0
+                     for r in self.slot_req], jnp.int32)
+                enable = any(
+                    self._sampling(self.slot_req[i]).temperature > 0
+                    for i in active)
+                key = self._split_key()
+            with obs.span("engine.step.dispatch"):
+                nxt, self.caches = self._run(
+                    self._decode, self.p, self.q, self.caches, tok, pos,
+                    key, temp, topk, enable)
+            with obs.span("engine.step.sync"):
+                nxt = np.asarray(nxt)
+            with obs.span("engine.step.record"):
+                for i in active:
+                    self.slot_pos[i] += 1
+                    self._next_tok[i] = nxt[i]
+                    self._record(i, int(nxt[i]))
+        obs.count("engine.ticks")
+        obs.count("engine.slot_ticks", len(active))
 
     def tokens(self, handle: RequestHandle):
         """Incremental token reader for one admitted request: yields each

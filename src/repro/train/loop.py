@@ -158,14 +158,16 @@ def make_train_step(forward: Forward, loss_fn: LossFn, tcfg: TrainConfig,
                    "gnorm": gnorm, "beta": beta}
         return params, newq, opt, metrics, tx_state
 
+    # every variant is named ``train_step``: jit names its program after
+    # the function (``jit_train_step`` in a profile's module list)
     if grad_tx is None:
-        def step_fn(params, qstate, opt: AdamWState, batch, step):
+        def train_step(params, qstate, opt: AdamWState, batch, step):
             return _step(params, qstate, opt, batch, step, None)[:4]
-        return step_fn
+        return train_step
 
-    def step_fn_tx(params, qstate, opt: AdamWState, batch, step, tx_state):
+    def train_step(params, qstate, opt: AdamWState, batch, step, tx_state):
         return _step(params, qstate, opt, batch, step, tx_state)
-    return step_fn_tx
+    return train_step
 
 
 def _make_compressed_step(forward: Forward, loss_fn: LossFn,
@@ -184,7 +186,7 @@ def _make_compressed_step(forward: Forward, loss_fn: LossFn,
     ``wire_layout``) is then the only gradient communication in the
     program.
     """
-    def step_fn_wire(params, qstate, opt: AdamWState, batch, step, tx_state):
+    def train_step(params, qstate, opt: AdamWState, batch, step, tx_state):
         beta = beta_sched(step)
         lr = lr_sched(step)
 
@@ -229,7 +231,7 @@ def _make_compressed_step(forward: Forward, loss_fn: LossFn,
                    "ebops": jnp.mean(ebops_s), "gnorm": gnorm, "beta": beta}
         return params, newq, opt, metrics, type(tx_state)(residual=residual)
 
-    return step_fn_wire
+    return train_step
 
 
 class Trainer:
