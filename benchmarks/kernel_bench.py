@@ -14,10 +14,19 @@ import jax.numpy as jnp
 
 from repro.kernels.hgq_quantize.kernel import DEFAULT_BLOCK_ROWS, LANE
 from repro.kernels.hgq_quantize.ref import hgq_quantize_ref
-from repro.kernels.qmatmul.kernel import DEFAULT_BK, DEFAULT_BM, DEFAULT_BN
+from repro.kernels.qmatmul.ops import PAD_BLOCK_K, qmatmul_tiles
 from repro.kernels.qmatmul.ref import pack_ref, qmatmul_ref
 
 from .common import emit, time_call
+
+
+def qmatmul_vmem_tile_bytes(M: int, K: int, N: int) -> int:
+    """VMEM working set of one grid step of the int8 ``qmatmul`` at these
+    shapes, with the tiles ``qmatmul_any`` picks: the f32 x block, the
+    int8 weight block and the f32 output/accumulator block."""
+    bm, bn, bk = qmatmul_tiles(M, K, N, False)
+    bk = bk or PAD_BLOCK_K   # no fitting divisor: qmatmul_any pads K
+    return bm * bk * 4 + bk * bn + bm * bn * 4
 
 
 def bench_kernels() -> List[str]:
@@ -46,8 +55,7 @@ def bench_kernels() -> List[str]:
         flops = 2.0 * M * K * N
         int8_bytes = K * N + 4 * N
         bf16_bytes = 2 * K * N
-        vmem = (DEFAULT_BM * DEFAULT_BK * 4 + DEFAULT_BK * DEFAULT_BN
-                + DEFAULT_BM * DEFAULT_BN * 4)
+        vmem = qmatmul_vmem_tile_bytes(M, K, N)
         lines.append(emit(
             f"kernel.qmatmul.{name}", us,
             f"flops={flops:.3g};weight_bytes_int8={int8_bytes};"

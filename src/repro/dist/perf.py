@@ -23,7 +23,7 @@ retrace it.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -161,21 +161,73 @@ def pack_params_for_serving(params: Any, plan=None) -> Any:
     return walk(params)
 
 
+def stored_weight(p: Dict[str, Any]
+                  ) -> Tuple[jax.Array, bool, Optional[jax.Array]]:
+    """(stored mantissa array, nibble-stored?, layer index or None) of a
+    packed weight dict.  A packed dict under ``p['layers']`` may carry a
+    ``layer`` index into a stacked ``[L, ...]`` array
+    (:func:`layer_scan_split`); this is the one reader of that key."""
+    layer = p.get("layer")
+    if "w_nib" in p:
+        return p["w_nib"], True, layer
+    return p["w_int8"], False, layer
+
+
 def packed_mantissas(p: Dict[str, Any]) -> jax.Array:
     """Full-width int8 mantissas ``[..., K, N]`` of a packed weight dict,
     whichever storage it uses (``w_int8`` as-is; ``w_nib`` sign-extend
-    unpacked along K).  The one accessor packed-kernel call sites route
-    through."""
-    if "w_nib" in p:
+    unpacked along K; a ``layer`` index picks that layer of a stacked
+    weight, :func:`layer_scan_split`).  The accessor for every reader
+    that dequantizes in XLA; the packed kernel reads the stored array."""
+    w, nib, layer = stored_weight(p)
+    if layer is not None:
+        w = jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
+    if nib:
         from ..kernels.qmatmul.ops import unpack_nibbles
-        nib = p["w_nib"]
-        return unpack_nibbles(nib, 2 * nib.shape[-2], axis=-2)
-    return p["w_int8"]
+        return unpack_nibbles(w, 2 * w.shape[-2], axis=-2)
+    return w
 
 
 def is_packed(p: Any) -> bool:
     """True for a serving-packed weight dict (either storage format)."""
     return isinstance(p, dict) and ("w_int8" in p or "w_nib" in p)
+
+
+def layer_scan_split(layers: Any) -> Tuple[Any, Any]:
+    """(xs, whole) for a ``lax.scan`` over a stacked layer tree.  Under
+    packed routing each packed ``[L, K, N]`` weight array leaves ``xs``
+    for ``whole``, and its dict gains ``layer`` (0..L-1) in ``xs`` in its
+    place; :func:`layer_scan_join` puts the array back whole into each
+    layer's slice, and the packed kernel reads that layer's blocks in
+    place — sliced by the scan, each layer's weight would be copied out
+    of the stack first.  Off, ``xs`` is ``layers`` and ``whole`` None."""
+    if not get_packed_matmul():
+        return layers, None
+
+    def split(t):
+        if is_packed(t) and stored_weight(t)[0].ndim == 3:
+            key = "w_nib" if "w_nib" in t else "w_int8"
+            rest = {k: v for k, v in t.items() if k != key}
+            rest["layer"] = jnp.arange(t[key].shape[0], dtype=jnp.int32)
+            return rest, {key: t[key]}
+        if isinstance(t, dict):
+            pairs = {k: split(v) for k, v in t.items()}
+            return ({k: a for k, (a, _) in pairs.items()},
+                    {k: b for k, (_, b) in pairs.items() if b})
+        return t, None
+
+    return split(layers)
+
+
+def layer_scan_join(lp: Any, whole: Any) -> Any:
+    """One layer's params from its scanned slice and the ``whole``
+    arrays :func:`layer_scan_split` held back."""
+    if not whole:
+        return lp
+    out = dict(lp)
+    for k, v in whole.items():
+        out[k] = v if k in ("w_int8", "w_nib") else layer_scan_join(lp[k], v)
+    return out
 
 
 def unpack_weight(p: Dict[str, Any]) -> jax.Array:

@@ -37,6 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..backend import resolve_interpret
 from ..hgq_quantize.kernel import DEFAULT_BLOCK_ROWS, LANE, _exact_exp2
+from ..qmatmul.kernel import nibble_planes
 from ..wire_pack.kernel import _floor_log2_pos
 
 NEG_INF = -1e30
@@ -57,12 +58,8 @@ def _unpack_planes(packed):
     lanes, the high nibbles (odd columns) the rest.  Up to that column
     order this is ``qmatmul.unpack_nibbles``; Mosaic cannot interleave
     lanes, and hdm is lane-aligned, so the planes concatenate in place.
-    The shifts run in int32 (Mosaic has no int8 shifts); arithmetic
-    shifts of the sign-extended byte give the int8 results exactly."""
-    p = packed.astype(jnp.int32)
-    lo = jax.lax.shift_right_arithmetic(jax.lax.shift_left(p, 28), 28)
-    hi = jax.lax.shift_right_arithmetic(p, 4)
-    return jnp.concatenate([lo, hi], axis=-1)
+    The sign extension is ``qmatmul``'s :func:`nibble_planes`."""
+    return jnp.concatenate(nibble_planes(packed), axis=-1)
 
 
 def _kv_quantize_kernel(x_ref, q_ref, f_ref, *, qmax):

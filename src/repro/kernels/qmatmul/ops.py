@@ -124,34 +124,74 @@ def unpack_nibbles(packed: jax.Array, orig: int,
     return jnp.moveaxis(m, -1, axis)
 
 
-def qmatmul_any(x: jax.Array, w_int: jax.Array, scale: jax.Array, *,
-                interpret: Optional[bool] = None, bm: int = 128,
-                bn: int = 128, bk: int = 512) -> jax.Array:
-    """x [..., K] @ packed w [K, N]: flattens leading dims and pads to the
-    (8, 128) tile grid.  ``interpret=None`` selects per backend
-    (``kernels.backend.default_interpret``); pass a bool to override."""
-    K, N = w_int.shape
+# Stored weight elements one grid step may hold, counted as the fp32
+# values it dequantizes to in VMEM (a nibble byte holds two).
+BLOCK_ELEMS = 1 << 20
+# Rows of x one grid step takes; smaller M runs as one block.
+BLOCK_ROWS = 256
+# K block of the last-resort path, for a K with no fitting divisor.
+PAD_BLOCK_K = 512
+
+
+def _round_up(v: int, base: int) -> int:
+    return -(-v // base) * base
+
+
+def qmatmul_tiles(M: int, Ks: int, Ns: int, nibbles: bool
+                  ) -> Tuple[int, int, Optional[int]]:
+    """(bm, bn, bk) for a weight stored with ``Ks`` rows along K and
+    ``Ns`` along N (nibble bytes count once).  K is one whole block where
+    a 128-wide N block of it fits :data:`BLOCK_ELEMS`, else the largest
+    multiple-of-128 divisor of K that does; ``bk`` is None when K has no
+    such divisor (the caller pads K).  N takes the widest multiple-of-
+    128 block that fits, or all of N; a partial last block only feeds
+    output columns that are never written back."""
+    budget = BLOCK_ELEMS // (2 if nibbles else 1)
+    bm = M if M <= BLOCK_ROWS else BLOCK_ROWS
+    if Ks * min(Ns, 128) <= budget:
+        bk = Ks
+    else:
+        bk = max((d for d in range(128, Ks, 128)
+                  if Ks % d == 0 and d * 128 <= budget), default=None)
+    fit = budget // (bk or PAD_BLOCK_K)
+    bn = Ns if Ns <= fit else max(128, fit // 128 * 128)
+    return bm, bn, bk
+
+
+def qmatmul_any(x: jax.Array, w: jax.Array, scale: Optional[jax.Array] = None,
+                *, nibbles: bool = False, transposed: bool = False,
+                layer: Optional[jax.Array] = None,
+                interpret: Optional[bool] = None) -> jax.Array:
+    """x [..., K] times a packed weight read as stored (``kernel``'s
+    module doc): ``[K, N]`` int8 by default, ``[K/2, N]`` with
+    ``nibbles``, ``[N, K]`` (a tied table) with ``transposed``, ``[N/2,
+    K]`` with both; a leading layer axis with ``layer``.  ``scale`` [N]
+    or None (unit).  Leading dims of x flatten into M; tiles come from
+    the shapes (:func:`qmatmul_tiles`), and only a K with no fitting
+    divisor pads (x and the weight, with zeros).  ``interpret=None``
+    selects per backend (``kernels.backend.default_interpret``); pass a
+    bool to override."""
+    Ns, Ks = w.shape[-2:] if transposed else w.shape[-2:][::-1]
+    K = 2 * Ks if nibbles and not transposed else Ks
+    N = 2 * Ns if nibbles and transposed else Ns
     lead = x.shape[:-1]
     M = math.prod(lead) if lead else 1
     x2 = x.reshape(M, K)
-
-    def _round_up(v, base):
-        return -(-v // base) * base
-
-    # every dim must be an exact multiple of its tile (partial blocks read
-    # out-of-bounds in the k-accumulation grid)
-    bm_ = min(bm, _round_up(M, 8))
-    bk_ = min(bk, _round_up(K, 128))
-    bn_ = min(bn, _round_up(N, 128))
-    M3, K3, N3 = _round_up(M, bm_), _round_up(K, bk_), _round_up(N, bn_)
-    if M3 > M or K3 > K:
-        x2 = jnp.pad(x2, ((0, M3 - M), (0, K3 - K)))
-    w2, s2 = w_int, scale
-    if K3 > K or N3 > N:
-        w2 = jnp.pad(w_int, ((0, K3 - K), (0, N3 - N)))
-        s2 = jnp.pad(scale, (0, N3 - N))
-    out = qmatmul(x2, w2, s2, bm=bm_, bn=bn_, bk=bk_, interpret=interpret)
-    return out[:M, :N].reshape(*lead, N)
+    bm, bn, bk = qmatmul_tiles(M, Ks, Ns, nibbles)
+    if bk is None:
+        # last resort: zero rows of K (zero nibble bytes decode to 0)
+        bk = PAD_BLOCK_K
+        pad = _round_up(Ks, bk) - Ks
+        x2 = jnp.pad(x2, ((0, 0), (0, pad * (K // Ks))))
+        kax = w.ndim - 1 if transposed else w.ndim - 2
+        w = jnp.pad(w, [(0, pad) if a == kax else (0, 0)
+                        for a in range(w.ndim)])
+    out = qmatmul(x2, w, scale, layer, nibbles=nibbles,
+                  transposed=transposed, bm=bm, bn=bn, bk=bk,
+                  interpret=interpret)
+    if nibbles and transposed:
+        out = out.transpose(1, 2, 0).reshape(M, N)
+    return out.reshape(*lead, N)
 
 
 def packed_bytes(w: jax.Array, f: jax.Array, vmin, vmax) -> float:

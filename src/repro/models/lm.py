@@ -122,6 +122,7 @@ class TransformerLM:
             else:
                 lp, lq = xs
                 cache_l = None
+            lp = layer_scan_join(lp, whole)
             h2, newlq, new_cache, (e, l) = TransformerLM._layer(
                 lp, lq, h, positions, cache_l, cache_pos, cfg, mode,
                 kv_bits=kv_bits)
@@ -131,8 +132,10 @@ class TransformerLM:
         if cfg.remat:
             body = jax.checkpoint(body,
                                   policy=jax.checkpoint_policies.nothing_saveable)
-        xs = (p["layers"], q["layers"]) if caches is None \
-            else (p["layers"], q["layers"], caches)
+        from ..dist.perf import layer_scan_join, layer_scan_split
+        layers, whole = layer_scan_split(p["layers"])
+        xs = (layers, q["layers"]) if caches is None \
+            else (layers, q["layers"], caches)
         (x, ebops, l1), out = jax.lax.scan(body, (x, jnp.float32(0.0),
                                                   jnp.float32(0.0)), xs)
         if caches is None:
@@ -171,18 +174,18 @@ class TransformerLM:
     def _logits(p, q, newq, h: QTensor, cfg: ModelConfig, mode, aux):
         if cfg.tie_embeddings:
             from ..dist.perf import (get_packed_matmul, is_packed,
-                                     packed_mantissas)
+                                     stored_weight)
             tbl = p["embed"]["table"]
             if is_packed(tbl) and get_packed_matmul():
                 # tied head with a packed table: scales are per-embedding-
                 # column (axis d), so they fold into the activation —
-                # h @ (m * s[None]).T == (h * s) @ m.T — leaving a unit
-                # per-output scale for the kernel
+                # h @ (m * s[None]).T == (h * s) @ m.T — and the kernel
+                # reads the [vocab, d] table as stored, unit-scaled
                 from ..kernels.qmatmul.ops import qmatmul_any
                 s_d = tbl["scale"].reshape(cfg.d_model)
-                logits = qmatmul_any(h.q.astype(jnp.float32) * s_d,
-                                     packed_mantissas(tbl).T,
-                                     jnp.ones((cfg.vocab,), jnp.float32))
+                w, nib, _ = stored_weight(tbl)
+                logits = qmatmul_any(h.q.astype(jnp.float32) * s_d, w,
+                                     nibbles=nib, transposed=True)
                 return constrain(logits, "b.m")
             from ..nn.common import get_qw
             wq = get_qw(tbl, mode)

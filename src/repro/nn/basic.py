@@ -56,18 +56,18 @@ class HDense:
     def apply(p, q, x: QTensor, *, mode: str, aux: Aux, act: str = ""
               ) -> Tuple[QTensor, Dict[str, Any]]:
         from ..dist.perf import (cast_for_matmul, get_compute_dtype,
-                                 get_packed_matmul, is_packed,
-                                 packed_mantissas)
+                                 get_packed_matmul, is_packed, stored_weight)
         if is_packed(p["kernel"]) and get_packed_matmul():
             # serving hot path (serving/packed.py): the packed mantissas
-            # stream straight into the fused dequant-matmul Pallas kernel
-            # (nibble-stored layers sign-extend to int8 first) — the
-            # weight bytes moved from HBM are the packed ones
+            # stream as stored (int8 or nibbles, one layer of a stack)
+            # into the fused dequant-matmul Pallas kernel — the weight
+            # bytes moved from HBM are the packed ones, once
             from ..kernels.qmatmul.ops import qmatmul_any
-            ki = packed_mantissas(p["kernel"])
-            y = qmatmul_any(x.q.astype(jnp.float32), ki,
-                            p["kernel"]["scale"].reshape(ki.shape[-1])
-                            ).astype(x.q.dtype)
+            k = p["kernel"]
+            w, nib, layer = stored_weight(k)
+            y = qmatmul_any(x.q.astype(jnp.float32), w,
+                            k["scale"].reshape(w.shape[-1]), nibbles=nib,
+                            layer=layer).astype(x.q.dtype)
         else:
             wq = get_qw(p["kernel"], mode)
             d_in, d_out = wq.q.shape

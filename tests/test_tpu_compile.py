@@ -46,8 +46,9 @@ def shape(topo):
 
 
 def _assert_kernel(fn, *args):
-    hlo = jax.jit(fn).lower(*args).compile().as_text()
-    assert 'custom_call_target="tpu_custom_call"' in hlo
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    return compiled
 
 
 @pytest.mark.parametrize("K,N", [(D, D), (D, KV * HD), (D, FF), (FF, D),
@@ -57,6 +58,34 @@ def test_qmatmul(shape, K, N):
     _assert_kernel(lambda x, w, s: qmatmul_any(x, w, s, interpret=False),
                    shape((SLOTS, K), jnp.float32), shape((K, N), jnp.int8),
                    shape((N,), jnp.float32))
+
+
+@pytest.mark.parametrize("K,N,nibbles,transposed",
+                         [(D, FF, True, False), (FF, D, True, False),
+                          (D, VOCAB, False, True)],
+                         ids=["gate_up_nib", "down_nib", "tied_head"])
+def test_qmatmul_as_stored(shape, K, N, nibbles, transposed):
+    """The serving forward's weights as the packed tree stores them:
+    nibbles ``[K/2, N]`` and the tied ``[vocab, d]`` table.  The weight
+    reaches the kernel as stored (no int8 op writes HBM), and the
+    head needs no weight-sized temporaries (291.7 MB when it was
+    transposed and padded per call)."""
+    stored = (N, K) if transposed else (K // 2 if nibbles else K, N)
+
+    def mm(x, w, s):
+        return qmatmul_any(x, w, None if transposed else s,
+                           nibbles=nibbles, transposed=transposed,
+                           interpret=False)
+    compiled = _assert_kernel(mm, shape((SLOTS, K), jnp.float32),
+                              shape(stored, jnp.int8),
+                              shape((N,), jnp.float32))
+    # int8 results written to HBM; the compiler's own prefetches of the
+    # weight into VMEM (memory space S(1)) move it once, as read
+    s8_hbm = [line for line in compiled.as_text().splitlines()
+              if " = s8[" in line and "parameter(" not in line
+              and "S(1)" not in line.split(" = ", 1)[1].split(" ", 1)[0]]
+    assert not s8_hbm, s8_hbm
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 def test_kv_quantize_rows(shape):
